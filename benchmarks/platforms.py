@@ -16,8 +16,6 @@ from __future__ import annotations
 
 import jax
 
-from repro.compat import set_mesh as compat_set_mesh
-
 from repro.apps.wordcount import WORDCOUNT_SPACE, build_wordcount, make_corpus
 from repro.configs.archs import get_arch
 from repro.configs.base import RunConfig, ShapeConfig
@@ -52,7 +50,7 @@ def lm_train_evaluator(repeats: int = 2):
 
     def builder(cfg):
         run = TRAIN_SPACE.to_run_config(cfg, RunConfig(mesh_model_parallel=1))
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             bundle = make_train_step(arch, run, LM_SHAPE, mesh)
             state = init_train_state(bundle)
             batch = bundle.model.make_inputs(LM_SHAPE)
@@ -60,7 +58,7 @@ def lm_train_evaluator(repeats: int = 2):
             fn = bundle.jit(donate=False)  # job re-runs from the same state
 
         def job(state=state):
-            with compat_set_mesh(mesh):
+            with jax.set_mesh(mesh):
                 s = state
                 for _ in range(LM_STEPS):
                     s, m = fn(s, batch)

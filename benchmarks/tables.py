@@ -442,9 +442,9 @@ def table_surrogate(budget: int = 24, seed: int = 3) -> List[Dict[str, Any]]:
 
 def table_kernels(budget: int = 10, seed: int = 0) -> List[Dict[str, Any]]:
     """Default vs study-tuned block configs per Pallas kernel, interpret
-    mode (kernel bodies execute on CPU — the relative ordering of block
-    configs is what transfers to hardware, the same way the WordCount tables
-    transfer the paper's method, not its cluster). Per kernel at one
+    mode (kernel bodies execute on CPU: the rows rank block configs for the
+    Pallas interpreter and exercise the tuning loop; they are not kernel
+    speed on any accelerator). Per kernel at one
     representative shape: a TPE session over the kernel's TunableSpace finds
     an incumbent, then default and tuned configs are re-measured back to
     back on the same evaluator and inputs. Rows are merged into
@@ -459,7 +459,8 @@ def table_kernels(budget: int = 10, seed: int = 0) -> List[Dict[str, Any]]:
     }
     rows = []
     for kernel, shape in shapes.items():
-        ev = make_kernel_evaluator(kernel, shape, repeats=3, seed=seed)
+        ev = make_kernel_evaluator(kernel, shape, repeats=3, seed=seed,
+                                   interpret=True)
         space = KERNEL_SPACES[kernel]
         with Study() as study:  # ephemeral: the table re-measures for itself
             out = study.optimize(ev.platform_key(), "tpe", ev, space=space,

@@ -9,8 +9,6 @@ import dataclasses
 
 import jax
 
-from repro.compat import set_mesh as compat_set_mesh
-
 from repro.configs.archs import get_arch
 from repro.configs.base import RunConfig, ShapeConfig
 from repro.checkpoint.manager import CheckpointManager
@@ -37,7 +35,7 @@ def main():
     run = RunConfig(mesh_model_parallel=1, learning_rate=1e-3)
     mesh = make_host_mesh(model_parallel=1)
 
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = make_train_step(arch, run, shape, mesh)
         state = init_train_state(bundle)
         n = sum(x.size for x in jax.tree.leaves(state["params"]))

@@ -23,7 +23,6 @@ import time
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
-from repro.compat import set_mesh as compat_set_mesh
 from repro.configs.base import ArchConfig, RunConfig, ShapeConfig
 from repro.core import roofline as rl
 from repro.core.space import TunableSpace
@@ -202,12 +201,14 @@ class RooflineEvaluator:
     def _evaluate(
         self, run: RunConfig, mp: int, full: bool = True
     ) -> Tuple[float, Dict[str, Any]]:
+        import jax
+
         from repro.distributed.steps import make_step
         from repro.launch.mesh import make_tuning_mesh
 
         mesh = make_tuning_mesh(mp, chips=self.chips, multi_pod=self.multi_pod)
 
-        with compat_set_mesh(mesh):
+        with jax.set_mesh(mesh):
             per_dev, probe_times = rl.extrapolated_costs(
                 self.arch, run, self.shape, mesh, make_step,
                 single_probe=not full,

@@ -40,9 +40,11 @@ process **before** the evaluator spec resolves (and therefore before the
 worker's first ``import jax``; jax reads ``CUDA_VISIBLE_DEVICES`` /
 ``JAX_PLATFORMS`` / ``XLA_FLAGS`` once, at backend init). N workers then run
 N truly concurrent trials instead of serializing on device 0. A guard after
-evaluator construction checks ``len(jax.devices()) == 1`` and fails worker
-init loudly if the pin didn't take (e.g. a ``fork`` context after jax was
-already imported — the env change lands too late to matter).
+evaluator construction checks that the worker sees exactly one device, of
+the pinned platform, and fails worker init loudly if the pin didn't take
+(e.g. a ``fork`` context after jax was already imported — the env change
+lands too late to matter — or an accelerator that failed to start and left
+jax on the CPU).
 
 A worker that vanishes mid-trial surfaces as EOF on its pipe; the parent
 reaps it, records the trial, and respawns a replacement lazily. Because
@@ -135,6 +137,20 @@ class EvaluatorSpec:
 # ----------------------------------------------------------- device pinning
 
 
+# libtpu's default port is 8476; each single-chip worker runtime on one host
+# needs a port of its own
+TPU_PIN_PORT_BASE = 8476
+
+
+def _tpu_chips_on_host() -> int:
+    """TPU chips attached to this host over PCI, found the way jax's own TPU
+    start-up finds them — from sysfs, without initialising a backend (the
+    parent must not take a chip its workers need)."""
+    from jax._src import hardware_utils
+
+    return hardware_utils.num_available_tpu_chips_and_device_id()[0]
+
+
 def _device_pin_env(slot: int, pin_devices: int) -> Dict[str, str]:
     """Env vars restricting one worker to one device (slot ``slot``).
 
@@ -144,9 +160,16 @@ def _device_pin_env(slot: int, pin_devices: int) -> Dict[str, str]:
     - CUDA/ROCm: narrow ``CUDA_VISIBLE_DEVICES`` to the slot's entry (keeps
       the parent's explicit ordering when it set a list), so the worker's
       device 0 *is* physical device ``slot``.
-    - TPU: one chip per process via the megacore-style bounds vars.
-    - CPU (this container, and any JAX_PLATFORMS=cpu run): a single host
-      device per worker — each worker is its own "chip".
+    - TPU (``JAX_PLATFORMS=tpu``, or unset on a host with TPU chips): one
+      chip per process — ``TPU_VISIBLE_CHIPS`` picks the chip, the 1x1x1
+      bounds make each worker its own single-chip slice (which is also what
+      lets libtpu load in several processes at once), and each worker's
+      runtime gets its own port.
+    - CPU (``JAX_PLATFORMS=cpu``, or a host with no accelerator): a single
+      host device per worker — each worker is its own "chip".
+
+    A TPU host never yields ``JAX_PLATFORMS=cpu``: a run that asked for no
+    platform gets the chips it has, not the host CPU.
     """
     cuda = os.environ.get("CUDA_VISIBLE_DEVICES", "").strip()
     plat = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip().lower()
@@ -155,14 +178,17 @@ def _device_pin_env(slot: int, pin_devices: int) -> Dict[str, str]:
         return {"CUDA_VISIBLE_DEVICES": ids[slot % len(ids)]}
     if plat in ("cuda", "gpu", "rocm"):
         return {"CUDA_VISIBLE_DEVICES": str(slot)}
-    if plat == "tpu" or os.environ.get("TPU_WORKER_ID") is not None:
+    if plat == "tpu" or (not plat and (
+            os.environ.get("TPU_WORKER_ID") is not None
+            or _tpu_chips_on_host() > 0)):
         return {
             "TPU_VISIBLE_CHIPS": str(slot),
             "TPU_PROCESS_BOUNDS": "1,1,1",
             "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_PORT": str(TPU_PIN_PORT_BASE + slot),
         }
-    # CPU fallback: force the host platform with exactly one device, dropping
-    # any inherited multi-device override (e.g. the roofline driver's 512)
+    # CPU: force the host platform with exactly one device, dropping any
+    # inherited multi-device override (e.g. the roofline driver's 512)
     xla = os.environ.get("XLA_FLAGS", "")
     xla = " ".join(
         f for f in xla.split()
@@ -174,9 +200,20 @@ def _device_pin_env(slot: int, pin_devices: int) -> Dict[str, str]:
     }
 
 
+def _pinned_platform(pin_env: Dict[str, str]) -> str:
+    """The ``jax.Device.platform`` a worker pinned by ``pin_env`` must see."""
+    if "TPU_VISIBLE_CHIPS" in pin_env:
+        return "tpu"
+    if "CUDA_VISIBLE_DEVICES" in pin_env:
+        return "gpu"
+    return "cpu"
+
+
 def _apply_pin_guard(pin_env: Optional[Dict[str, str]]) -> Optional[str]:
     """Worker-side post-init check: if pinning was requested and the
-    evaluator pulled jax in, the worker must see exactly one device.
+    evaluator pulled jax in, the worker must see exactly one device, of the
+    platform the parent pinned it to (jax falls back to the CPU when an
+    accelerator fails to start — that must fail here, not time the host).
     Returns an error message (init failure) or None."""
     if not pin_env:
         return None
@@ -186,14 +223,20 @@ def _apply_pin_guard(pin_env: Optional[Dict[str, str]]) -> Optional[str]:
     if jax is None:
         return None  # evaluator never imported jax — nothing to mispin
     try:
-        n = len(jax.devices())
+        devices = jax.devices()
     except Exception as e:  # noqa: BLE001 — backend init itself broke
         return f"device pin guard: jax.devices() failed: {type(e).__name__}: {e}"
-    if n != 1:
+    if len(devices) != 1:
         return (
-            f"device pin guard: worker sees {n} devices, expected exactly 1 — "
-            "the pin env landed after jax initialised (use mp_context='spawn', "
-            "and never import jax at executors module scope)"
+            f"device pin guard: worker sees {len(devices)} devices, expected "
+            "exactly 1 — the pin env landed after jax initialised (use "
+            "mp_context='spawn', and never import jax at executors module scope)"
+        )
+    want = _pinned_platform(pin_env)
+    if devices[0].platform != want:
+        return (
+            f"device pin guard: worker's device is {devices[0].platform!r}, "
+            f"pinned to {want!r} — the {want} backend did not start"
         )
     return None
 

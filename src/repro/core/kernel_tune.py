@@ -175,15 +175,16 @@ class KernelEvaluator:
 
     Inputs and the oracle output are generated once per evaluator (seeded)
     and reused across trials, so every variant is measured on identical
-    data. ``interpret=True`` (the default) runs the Pallas kernel bodies on
-    CPU — the CI-safe mode; on real accelerators pass ``interpret=False``.
+    data. Kernels are compiled for the default device; ``interpret=True``
+    is the explicit opt-in that runs their bodies in the Pallas interpreter
+    (CPU tests) — never a speed measurement.
     """
 
     kernel: str
     shape: Tuple[int, ...]
     dtype: str = "f32"
     repeats: int = 5
-    interpret: bool = True
+    interpret: bool = False
     tolerance: Optional[float] = None
     seed: int = 0
     spec: Optional[Any] = None  # EvaluatorSpec for subprocess workers
@@ -365,7 +366,7 @@ def make_kernel_evaluator(
     dtype: str = "f32",
     *,
     repeats: int = 5,
-    interpret: bool = True,
+    interpret: bool = False,
     tolerance: Optional[float] = None,
     seed: int = 0,
 ) -> KernelEvaluator:

@@ -22,10 +22,9 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import axis_size as compat_axis_size
-from repro.compat import shard_map as compat_shard_map
 from repro.configs.base import ArchConfig, RunConfig, ShapeConfig
 from repro.distributed.sharding import (
     batch_partition_specs,
@@ -58,12 +57,10 @@ class StepBundle:
     mesh: Any = None
 
     def jit(self, donate: bool = True):
-        from repro.compat import concrete_shardings
-
         return jax.jit(
             self.fn,
-            in_shardings=concrete_shardings(self.in_shardings, self.mesh),
-            out_shardings=concrete_shardings(self.out_shardings, self.mesh),
+            in_shardings=self.in_shardings,
+            out_shardings=self.out_shardings,
             donate_argnums=self.donate_argnums if donate else (),
         )
 
@@ -75,8 +72,6 @@ class StepBundle:
 
     def place(self, mesh, *args):
         """device_put concrete inputs onto their declared shardings."""
-        from jax.sharding import NamedSharding, PartitionSpec
-
         def put(tree, ps):
             return jax.tree.map(
                 lambda x, p: jax.device_put(x, NamedSharding(mesh, p)),
@@ -86,6 +81,23 @@ class StepBundle:
             )
 
         return tuple(put(a, p) for a, p in zip(args, self.in_shardings))
+
+    def init_params(self, rng):
+        """Random parameters made by one jit whose outputs carry the bundle's
+        parameter shardings: each device builds only its own shard, so no
+        leaf is ever whole on one device."""
+        return jax.jit(
+            self.model.init_params,
+            out_shardings=_named(self.mesh, self.in_shardings[0]),
+        )(rng)
+
+
+def _named(mesh, specs):
+    """PartitionSpec tree -> NamedSharding tree on ``mesh``."""
+    return jax.tree.map(
+        lambda p: NamedSharding(mesh, p), specs,
+        is_leaf=lambda x: isinstance(x, P),
+    )
 
 
 def _effective_run(run: RunConfig) -> RunConfig:
@@ -194,46 +206,13 @@ def make_train_step(
             loss, metrics, grads = grads_over_batch(state["params"], batch)
             return apply_update(state, grads, loss, metrics)
 
-    elif not hasattr(jax, "shard_map"):
-        # Legacy-jax fallback: XLA's SPMD partitioner on jaxlib 0.4.x cannot
-        # handle the partial-manual (pod-manual, data/model-auto) shard_map
-        # region below. Express the same computation in pure GSPMD instead:
-        # chunk the batch into an explicit pod-sharded leading dim, vmap the
-        # per-pod grads (each pod computes only its own chunk), and reduce the
-        # int8-quantized chunks with an int32 sum over the pod-sharded dim —
-        # which XLA lowers to the same narrow cross-pod all-reduce.
-        from jax.sharding import NamedSharding
-
-        def train_step(state, batch):
-            params = state["params"]
-
-            def chunk(x):
-                if x.ndim == 0:
-                    return x
-                c = x.reshape((n_pod, x.shape[0] // n_pod) + x.shape[1:])
-                spec = P(*(("pod",) + (None,) * (c.ndim - 1)))
-                return jax.lax.with_sharding_constraint(c, NamedSharding(mesh, spec))
-
-            batch_c = jax.tree.map(chunk, batch)
-
-            def per_pod(mb):
-                return grads_over_batch(params, mb)
-
-            loss_p, metrics_p, grads_p = jax.vmap(per_pod)(batch_c)
-            synced, new_err = compression.compress_sum_chunked_tree(
-                grads_p, state["err"]
-            )
-            loss = loss_p.mean()
-            metrics = jax.tree.map(lambda m: m.mean(0), metrics_p)
-            return apply_update(state, synced, loss, metrics, new_err)
-
     else:
         # Partial-manual shard_map over the pod axis: pod-local grads, int8
         # error-feedback all-reduce across pods, everything else GSPMD.
         def pod_body(params, err, batch):
             loss, metrics, grads = grads_over_batch(params, batch)
             synced, new_err = compression.compress_psum_pod_tree(grads, err)
-            n = compat_axis_size("pod")
+            n = jax.lax.axis_size("pod")
             loss = jax.lax.psum(loss, "pod") / n
             metrics = jax.tree.map(lambda m: jax.lax.psum(m, "pod") / n, metrics)
             return loss, metrics, synced, new_err
@@ -248,7 +227,7 @@ def make_train_step(
 
         def train_step(state, batch):
             params = state["params"]
-            body = compat_shard_map(
+            body = jax.shard_map(
                 pod_body,
                 mesh=mesh,
                 in_specs=(replicate(params), replicate(state["err"]), pod_batch_specs),
@@ -272,14 +251,8 @@ def make_train_step(
         "step": P(),
     }
     if compress:
-        if hasattr(jax, "shard_map"):
-            state_abs["err"] = compression.abstract_error_state(params_abs)
-            state_ps["err"] = param_ps
-        else:  # chunked fallback keeps one residual per pod: [n_pod, *param]
-            state_abs["err"] = compression.abstract_chunked_error_state(
-                params_abs, n_pod
-            )
-            state_ps["err"] = jax.tree.map(lambda _: P("pod"), params_abs)
+        state_abs["err"] = compression.abstract_error_state(params_abs)
+        state_ps["err"] = param_ps
     batch_abs = model.input_specs(shape)
 
     metrics_ps = {"loss": P(), "grad_norm": P(), "lr": P(), "ce": P(), "aux": P()}
@@ -297,20 +270,27 @@ def make_train_step(
 
 
 def init_train_state(bundle: StepBundle, rng=None):
-    """Real initial state (smoke tests / examples)."""
+    """Real initial train state, built by one jit whose outputs carry the
+    bundle's state shardings (no leaf is ever materialized whole on one
+    device)."""
     model = bundle.model
     rng = rng if rng is not None else jax.random.PRNGKey(0)
-    params = model.init_params(rng)
     cfg = _adamw_cfg(model.run)
-    state = {
-        "params": params,
-        "opt": init_opt_state(params, cfg),
-        "step": jnp.zeros((), jnp.int32),
-    }
-    if "err" in bundle.abstract_inputs[0]:
-        err_abs = bundle.abstract_inputs[0]["err"]
-        state["err"] = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), err_abs)
-    return state
+    state_abs, state_ps = bundle.abstract_inputs[0], bundle.in_shardings[0]
+
+    def init(rng):
+        params = model.init_params(rng)
+        state = {
+            "params": params,
+            "opt": init_opt_state(params, cfg),
+            "step": jnp.zeros((), jnp.int32),
+        }
+        if "err" in state_abs:
+            state["err"] = jax.tree.map(
+                lambda a: jnp.zeros(a.shape, a.dtype), state_abs["err"])
+        return state
+
+    return jax.jit(init, out_shardings=_named(bundle.mesh, state_ps))(rng)
 
 
 # ---------------------------------------------------------------------------

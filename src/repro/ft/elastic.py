@@ -13,7 +13,6 @@ from typing import Optional, Tuple
 
 import jax
 
-from repro.compat import mesh_kwargs
 from repro.configs.base import ArchConfig
 
 
@@ -59,7 +58,7 @@ def make_elastic_mesh(n_devices: int, **kw):
     return jax.sharding.Mesh(
         np.asarray(devices).reshape(data, model),
         ("data", "model"),
-        **mesh_kwargs(2),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
     )
 
 

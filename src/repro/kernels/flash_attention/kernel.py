@@ -15,8 +15,8 @@ around SRAM/shared-memory; here the tiling is driven by VMEM capacity and the
     exposed to the paper's tuner; both must be multiples of 128 to keep the
     MXU systolic array full.
   - GQA: the kv BlockSpec maps query-head h → kv-head h·Hkv//Hq, so K/V
-    blocks are fetched once per query head directly from the (B,T,Hkv,Dh)
-    layout — no repeated/materialized K/V.
+    blocks are fetched once per query head from a head-major (B,Hkv,T,Dh)
+    copy — no repeated/materialized K/V.
   - causal + sliding-window masking is applied with block-level early-exit:
     fully-masked (q-block, kv-block) pairs are skipped before the matmul
     (``@pl.when``), which is where the causal 2× win comes from.
@@ -39,10 +39,10 @@ NEG_INF = -1e30
 
 def _kernel(
     # refs
-    q_ref,  # (1, block_q, 1, dh)
-    k_ref,  # (1, block_kv, 1, dh)
-    v_ref,  # (1, block_kv, 1, dh)
-    o_ref,  # (1, block_q, 1, dh)
+    q_ref,  # (block_q, dh)
+    k_ref,  # (block_kv, dh)
+    v_ref,  # (block_kv, dh)
+    o_ref,  # (block_q, dh)
     m_scr,  # (block_q,) f32 running max
     l_scr,  # (block_q,) f32 running denominator
     acc_scr,  # (block_q, dh) f32 accumulator
@@ -78,9 +78,9 @@ def _kernel(
 
     @pl.when(live)
     def _compute():
-        q = q_ref[0, :, 0, :].astype(jnp.float32) * scale  # (bq, dh)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)  # (bkv, dh)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)
+        q = q_ref[...].astype(jnp.float32) * scale  # (bq, dh)
+        k = k_ref[...].astype(jnp.float32)  # (bkv, dh)
+        v = v_ref[...].astype(jnp.float32)
 
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -111,7 +111,7 @@ def _kernel(
     @pl.when(ki == n_kv - 1)
     def _finalize():
         denom = jnp.maximum(l_scr[...], 1e-30)[:, None]
-        o_ref[0, :, 0, :] = (acc_scr[...] / denom).astype(o_ref.dtype)
+        o_ref[...] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
 def flash_attention_fwd(
@@ -163,16 +163,20 @@ def flash_attention_fwd(
         t_valid=t_valid,
     )
 
+    # heads ahead of the sequence: a (block, dh) tile is then the trailing
+    # two dims of the array, which is the only tiling Mosaic accepts when a
+    # head axis sits between them (dh is rarely a multiple of 128)
+    q, k, v = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     out = pl.pallas_call(
         kernel,
         grid=(b, hq, n_q, n_kv),
         in_specs=[
-            pl.BlockSpec((1, block_q, 1, dh), lambda b_, h, qi, ki: (b_, qi, h, 0)),
-            pl.BlockSpec((1, block_kv, 1, dh), lambda b_, h, qi, ki: (b_, ki, h * hkv // hq, 0)),
-            pl.BlockSpec((1, block_kv, 1, dh), lambda b_, h, qi, ki: (b_, ki, h * hkv // hq, 0)),
+            pl.BlockSpec((None, None, block_q, dh), lambda b_, h, qi, ki: (b_, h, qi, 0)),
+            pl.BlockSpec((None, None, block_kv, dh), lambda b_, h, qi, ki: (b_, h * hkv // hq, ki, 0)),
+            pl.BlockSpec((None, None, block_kv, dh), lambda b_, h, qi, ki: (b_, h * hkv // hq, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_q, 1, dh), lambda b_, h, qi, ki: (b_, qi, h, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, sp, hq, dh), q.dtype),
+        out_specs=pl.BlockSpec((None, None, block_q, dh), lambda b_, h, qi, ki: (b_, h, qi, 0)),
+        out_shape=jax.ShapeDtypeStruct((b, hq, sp, dh), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((block_q,), jnp.float32),
             pltpu.VMEM((block_q,), jnp.float32),
@@ -180,4 +184,4 @@ def flash_attention_fwd(
         ],
         interpret=interpret,
     )(q, k, v)
-    return out[:, :s]
+    return out.transpose(0, 2, 1, 3)[:, :s]

@@ -29,17 +29,20 @@ def _kernel(dt_ref, u_ref, b_ref, c_ref, a_ref, y_ref, h_scr, *, chunk: int):
     def _init():
         h_scr[...] = jnp.zeros_like(h_scr)
 
-    dt = dt_ref[0].astype(jnp.float32)  # (C, dib)
-    u = u_ref[0].astype(jnp.float32)  # (C, dib)
-    b_t = b_ref[0].astype(jnp.float32)  # (C, N)
-    c_t = c_ref[0].astype(jnp.float32)  # (C, N)
-    a = a_ref[...].astype(jnp.float32)  # (dib, N)
+    a = a_ref[...].astype(jnp.float32)  # (N, dib)
 
+    # the state is held (N, dib): channels on lanes, state on sublanes, so a
+    # time step reads one (1, dib) row of dt/u and one (N, 1) column of B/C
+    # straight from the refs (Mosaic cannot index a loaded value by the
+    # loop counter)
     def step(t, _):
-        da = jnp.exp(dt[t][:, None] * a)  # (dib, N)
-        h = da * h_scr[...] + (dt[t] * u[t])[:, None] * b_t[t][None, :]
+        dt = dt_ref[pl.ds(t, 1), :].astype(jnp.float32)  # (1, dib)
+        u = u_ref[pl.ds(t, 1), :].astype(jnp.float32)
+        b_t = b_ref[t].astype(jnp.float32)  # (N, 1)
+        c_t = c_ref[t].astype(jnp.float32)
+        h = jnp.exp(dt * a) * h_scr[...] + b_t * (dt * u)
         h_scr[...] = h
-        y_ref[0, t, :] = jnp.sum(h * c_t[t][None, :], axis=1).astype(y_ref.dtype)
+        y_ref[pl.ds(t, 1), :] = jnp.sum(h * c_t, axis=0, keepdims=True).astype(y_ref.dtype)
         return 0
 
     jax.lax.fori_loop(0, chunk, step, 0)
@@ -51,6 +54,10 @@ def ssm_scan(dt, u, b_t, c_t, a, *, chunk: int = 128, d_block: int = 256,
     (the h·C contraction; caller adds the D-skip and gating)."""
     b, s, di = dt.shape
     n = a.shape[1]
+    dtype = dt.dtype
+    # the kernel reads one time step's row at a dynamic sublane offset, which
+    # Mosaic can load only from unpacked (32-bit) tiles
+    dt, u, b_t, c_t = (x.astype(jnp.float32) for x in (dt, u, b_t, c_t))
     d_block = min(d_block, di)
     assert di % d_block == 0, (di, d_block)
     pad = (-s) % chunk
@@ -60,19 +67,16 @@ def ssm_scan(dt, u, b_t, c_t, a, *, chunk: int = 128, d_block: int = 256,
     sp = s + pad
     n_chunks = sp // chunk
 
+    row = pl.BlockSpec((None, chunk, d_block), lambda b_, dbi, ci: (b_, ci, dbi))
+    col = pl.BlockSpec((None, chunk, n, 1), lambda b_, dbi, ci: (b_, ci, 0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, chunk=chunk),
         grid=(b, di // d_block, n_chunks),
-        in_specs=[
-            pl.BlockSpec((1, chunk, d_block), lambda b_, dbi, ci: (b_, ci, dbi)),
-            pl.BlockSpec((1, chunk, d_block), lambda b_, dbi, ci: (b_, ci, dbi)),
-            pl.BlockSpec((1, chunk, n), lambda b_, dbi, ci: (b_, ci, 0)),
-            pl.BlockSpec((1, chunk, n), lambda b_, dbi, ci: (b_, ci, 0)),
-            pl.BlockSpec((d_block, n), lambda b_, dbi, ci: (dbi, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, chunk, d_block), lambda b_, dbi, ci: (b_, ci, dbi)),
-        out_shape=jax.ShapeDtypeStruct((b, sp, di), dt.dtype),
-        scratch_shapes=[pltpu.VMEM((d_block, n), jnp.float32)],
+        in_specs=[row, row, col, col,
+                  pl.BlockSpec((n, d_block), lambda b_, dbi, ci: (0, dbi))],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((b, sp, di), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((n, d_block), jnp.float32)],
         interpret=interpret,
-    )(dt, u, b_t, c_t, a)
-    return out[:, :s]
+    )(dt, u, b_t[..., None], c_t[..., None], a.T)
+    return out[:, :s].astype(dtype)

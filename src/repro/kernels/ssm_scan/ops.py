@@ -30,12 +30,14 @@ def snap_d_block(d_block: int, di: int) -> int:
 
 def vmem_footprint(chunk: int, d_block: int, n: int, dtype_bytes: int = 4) -> int:
     """Analytic per-core VMEM bytes for one (batch, d_block, chunk) grid
-    step: the dt/u/out (chunk × d_block) and B/C (chunk × n) tiles plus the
-    (d_block × n) A row at the input dtype, the (d_block × n) f32 state
-    scratch, and the f32 working tiles the in-kernel scan materializes.
-    Monotone in both ``chunk`` and ``d_block``."""
+    step: the dt/u/out (chunk × d_block) tiles and the B/C tiles (chunk
+    (n, 1) columns, each padded to n rounded up to 8 sublanes × 128 lanes),
+    all f32 whatever the input dtype, the (n × d_block) A tile at the input
+    dtype, the (n × d_block) f32 state scratch, and the f32 working tiles the
+    in-kernel scan materializes. Monotone in both ``chunk`` and ``d_block``."""
     c, db, n = int(chunk), int(d_block), int(n)
-    tiles = (3 * c * db + 2 * c * n + db * n) * int(dtype_bytes)
+    cols = 2 * c * (-(-n // 8) * 8) * 128
+    tiles = (3 * c * db + cols) * 4 + db * n * int(dtype_bytes)
     scratch = db * n * 4
     work = (c * db + c * n) * 4
     return tiles + scratch + work

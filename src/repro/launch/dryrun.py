@@ -30,8 +30,6 @@ from pathlib import Path
 
 import jax
 
-from repro.compat import set_mesh as compat_set_mesh
-
 from repro.configs.base import SHAPES, RunConfig
 from repro.configs.archs import ARCH_NAMES, applicable_shapes, get_arch
 from repro.core import roofline as rl
@@ -69,7 +67,7 @@ def run_cell(
         cell["skip_reason"] = "inapplicable shape for this architecture (DESIGN.md §6)"
         return cell
 
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         t0 = time.time()
         bundle = make_step(arch, run, shape, mesh)
         lowered = bundle.lower()

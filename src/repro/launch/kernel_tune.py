@@ -19,8 +19,9 @@ On a multi-chip host, fan trials out one-device-per-worker:
 
 Shapes are ``x``-separated dims per kernel: flash ``B x S x Hq x Hkv x Dh``,
 rwkv6 ``B x S x H x Hd``, ssm_scan ``B x S x Di x N`` (defaults in
-``DEFAULT_SHAPES``). Interpret mode (the default) runs kernel bodies on CPU
-— CI-safe; pass ``--no-interpret`` on a real accelerator.
+``DEFAULT_SHAPES``). Kernels are compiled for the accelerator JAX finds;
+``--interpret`` runs their bodies in the Pallas interpreter instead (the
+CPU tests' mode — its timings say nothing about the chip).
 """
 from __future__ import annotations
 
@@ -39,6 +40,7 @@ from repro.core.kernel_tune import (
     write_tuned_entries,
 )
 from repro.kernels import DEFAULT_TABLE_PATH
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.tune import add_engine_args, engine_config, open_study
 
 
@@ -75,9 +77,10 @@ def main(argv=None):
     ap.add_argument("--min-fidelity", type=float, default=1.0 / 3.0)
     ap.add_argument("--repeats", type=int, default=3,
                     help="timed runs per trial (best-of)")
-    ap.add_argument("--no-interpret", dest="interpret", action="store_false",
-                    help="run compiled kernels on the real accelerator "
-                         "instead of interpret mode")
+    ap.add_argument("--interpret", action="store_true",
+                    help="run the kernel bodies in the Pallas interpreter "
+                         "(on the CPU, for tests) instead of compiling them "
+                         "for the accelerator")
     ap.add_argument("--tolerance", type=float, default=None,
                     help="relative-error numerics gate (default per dtype)")
     ap.add_argument("--transfer", default="off",
@@ -93,6 +96,7 @@ def main(argv=None):
                     help="write the per-cell summary JSON")
     add_engine_args(ap)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     kernels = list(KERNEL_NAMES) if args.kernel == "all" else [args.kernel]
     if args.shapes and len(kernels) > 1:
@@ -111,6 +115,9 @@ def main(argv=None):
     else:  # tpe / random
         budget, kwargs = args.budget, dict(seed=args.seed)
 
+    # interpret-mode timings rank block sizes for the Pallas interpreter, not
+    # for the chip: every table entry says which it was tuned under
+    mode = "interpret" if args.interpret else "compiled"
     summaries, table_updates = {}, {}
     fresh = memo = cached = 0
     study = open_study(args, engine_config(args))
@@ -140,7 +147,8 @@ def main(argv=None):
                         kernel, args.dtype, evaluator.shape_class(),
                         outcome.best_config, outcome.best_time,
                         source=f"study:{args.study or 'ephemeral'}"
-                               f" algo={args.algorithm} seed={args.seed}",
+                               f" algo={args.algorithm} seed={args.seed}"
+                               f" mode={mode}",
                     ))
 
     report = {

@@ -3,8 +3,15 @@ this module never touches JAX device state."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.compat import make_mesh
+
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``: shardings propagate through
+    GSPMD from the in/out shardings and ``with_sharding_constraint`` hints
+    (jax's default is ``Explicit``, which the model code is not written for)."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes), devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -12,7 +19,7 @@ def make_production_mesh(*, multi_pod: bool = False):
     (data × model); multi-pod adds a leading pod axis (2 × 16 × 16 = 512)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_tuning_mesh(model_parallel: int, *, chips: int = 256, multi_pod: bool = False):
@@ -22,16 +29,18 @@ def make_tuning_mesh(model_parallel: int, *, chips: int = 256, multi_pod: bool =
         raise ValueError(f"model_parallel {model_parallel} !| chips {chips}")
     data = chips // model_parallel
     if multi_pod:
-        return make_mesh((2, data, model_parallel), ("pod", "data", "model"))
-    return make_mesh((data, model_parallel), ("data", "model"))
+        return auto_mesh((2, data, model_parallel), ("pod", "data", "model"))
+    return auto_mesh((data, model_parallel), ("data", "model"))
 
 
-def make_host_mesh(model_parallel: int = 1, *, pod: int = 0):
-    """Small mesh over however many (possibly fake) devices exist — used by
-    tests and CPU examples."""
-    n = len(jax.devices())
+def make_host_mesh(model_parallel: int = 1, *, pod: int = 0, devices=None):
+    """Mesh over ``devices`` (default: every device this process sees, real
+    or fake) — the serving/training drivers' mesh, and the tests'."""
+    devices = jax.devices() if devices is None else list(devices)
+    n = len(devices)
     if pod:
         data = n // (model_parallel * pod)
-        return make_mesh((pod, data, model_parallel), ("pod", "data", "model"))
+        return auto_mesh((pod, data, model_parallel), ("pod", "data", "model"),
+                         devices)
     data = n // model_parallel
-    return make_mesh((data, model_parallel), ("data", "model"))
+    return auto_mesh((data, model_parallel), ("data", "model"), devices)

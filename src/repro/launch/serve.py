@@ -34,9 +34,12 @@ from __future__ import annotations
 import argparse
 import json
 import time
+from dataclasses import dataclass
 from pathlib import Path
+from typing import Any
 
 from repro.configs.archs import ARCH_NAMES
+from repro.launch.compile_cache import use_compile_cache
 
 ONLINE_TRACES = ("flat", "regression", "drift")
 
@@ -106,6 +109,7 @@ def load_tuned_config(path: Path) -> dict:
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    use_compile_cache()
     if args.online_tune:
         if args.study is None:
             raise SystemExit("--online-tune requires --study DIR")
@@ -116,16 +120,45 @@ def main(argv=None):
 # --------------------------------------------------------------- offline path
 
 
-def _measured_serve(run, args, monitor):
-    """One full serve of ``run``: compile + warm up untimed, then measure
-    prefill latency and per-step decode latencies into ``monitor`` (one
-    metrics window per --window-steps decode steps).
-
-    Returns (t_prefill, t_decode, generated_token_array)."""
+def grow_caches(caches, extra: int):
+    """Grow prefill caches (capacity = prompt) by ``extra`` decode slots."""
     import jax
     import jax.numpy as jnp
 
-    from repro.compat import set_mesh as compat_set_mesh
+    def grow(path, x):
+        name = path[-1].key if hasattr(path[-1], "key") else ""
+        if name in ("k", "v", "ks", "vs"):
+            pad = [(0, 0)] * x.ndim
+            pad[2] = (0, extra)
+            return jnp.pad(x, pad)
+        return x
+
+    return jax.tree_util.tree_map_with_path(grow, caches)
+
+
+@dataclass
+class ServeRun:
+    """What one measured serve produced. ``prefill_logits`` /
+    ``decode_logits`` are the timed prefill's and first timed decode step's
+    (B, V) logits, kept with the sharded ``params`` and the prompt ``batch``
+    so a caller can replay both steps elsewhere and compare."""
+
+    t_prefill: float
+    t_decode: float
+    tokens: Any  # (B, max_new) generated ids
+    params: Any
+    batch: Any
+    prefill_logits: Any
+    decode_logits: Any
+
+
+def _measured_serve(run, args, monitor) -> ServeRun:
+    """One full serve of ``run``: compile + warm up untimed, then measure
+    prefill latency and per-step decode latencies into ``monitor`` (one
+    metrics window per --window-steps decode steps)."""
+    import jax
+    import jax.numpy as jnp
+
     from repro.configs.base import ShapeConfig
     from repro.configs.archs import get_arch
     from repro.distributed.steps import make_decode_step, make_prefill_step
@@ -137,28 +170,19 @@ def _measured_serve(run, args, monitor):
     decode_shape = ShapeConfig("cli_decode", total, args.batch, "decode")
     mesh = make_host_mesh(model_parallel=run.mesh_model_parallel)
 
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pre = make_prefill_step(arch, run, prefill_shape, mesh)
         dec = make_decode_step(arch, run, decode_shape, mesh)
         model = pre.model
-        params = model.init_params(jax.random.PRNGKey(0))
+        params = pre.init_params(jax.random.PRNGKey(0))
         batch = model.make_inputs(prefill_shape)
 
         prefill_fn = pre.jit()
         decode_fn = dec.jit()
 
-        # grow prefill caches (capacity=prompt) to decode capacity (total)
-        def grow(path, x):
-            name = path[-1].key if hasattr(path[-1], "key") else ""
-            if name in ("k", "v", "ks", "vs"):
-                pad = [(0, 0)] * x.ndim
-                pad[2] = (0, args.max_new)
-                return jnp.pad(x, pad)
-            return x
-
         def prefilled():
             logits, caches = jax.block_until_ready(prefill_fn(params, batch))
-            return logits, jax.tree_util.tree_map_with_path(grow, caches)
+            return logits, grow_caches(caches, args.max_new)
 
         # untimed warmup: the first prefill_fn/decode_fn calls compile, which
         # must not land inside the timed loop. The decode step donates its
@@ -175,6 +199,8 @@ def _measured_serve(run, args, monitor):
         logits, caches = prefilled()
         t_prefill = time.perf_counter() - t0
 
+        prefill_logits = logits
+        decode_logits = None
         tokens = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
         generated = [tokens]
         steps = args.max_new - 1
@@ -192,6 +218,8 @@ def _measured_serve(run, args, monitor):
             tokens = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
             jax.block_until_ready(tokens)
             monitor.record(time.perf_counter() - t_step, tokens=args.batch)
+            if decode_logits is None:
+                decode_logits = logits
             generated.append(tokens)
             in_window += 1
             if in_window >= args.window_steps:
@@ -202,7 +230,8 @@ def _measured_serve(run, args, monitor):
             monitor.end_window()
 
     out = jnp.concatenate(generated, axis=1)
-    return t_prefill, t_decode, out
+    return ServeRun(t_prefill, t_decode, out, params, batch,
+                    prefill_logits, decode_logits)
 
 
 def run_offline(args) -> int:
@@ -220,7 +249,8 @@ def run_offline(args) -> int:
         run = SERVE_SPACE.to_run_config(tuned, run)
 
     monitor = DecodeWindowMonitor(clock=time.perf_counter)
-    t_prefill, t_decode, out = _measured_serve(run, args, monitor)
+    served = _measured_serve(run, args, monitor)
+    t_prefill, t_decode, out = served.t_prefill, served.t_decode, served.tokens
 
     n_new = args.max_new * args.batch
     print(f"prefill: {args.batch}×{args.prompt_len} tokens in {t_prefill:.3f}s")

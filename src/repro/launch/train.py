@@ -17,14 +17,13 @@ from pathlib import Path
 
 import jax
 
-from repro.compat import set_mesh as compat_set_mesh
-
 from repro.configs.base import SHAPES, RunConfig, ShapeConfig
 from repro.configs.archs import ARCH_NAMES, get_arch
 from repro.checkpoint.manager import CheckpointManager
 from repro.data.pipeline import PipelineConfig, SyntheticLMPipeline
 from repro.distributed.steps import init_train_state, make_train_step
 from repro.ft.runner import ResilientTrainer, RunnerConfig
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_host_mesh
 
 
@@ -41,6 +40,7 @@ def main(argv=None):
     ap.add_argument("--fail-at", type=int, nargs="*", default=[])
     ap.add_argument("--tuned-config", type=Path, default=None)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     arch = get_arch(args.arch, smoke=args.smoke)
     shape = ShapeConfig("cli_train", args.seq, args.batch, "train")
@@ -52,10 +52,9 @@ def main(argv=None):
         run = TRAIN_SPACE.to_run_config(knobs, run)
     mesh = make_host_mesh(model_parallel=args.model_parallel)
 
-    with compat_set_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = make_train_step(arch, run, shape, mesh)
         state = init_train_state(bundle)
-        (state,) = bundle.place(mesh, state)
         step_fn = bundle.jit()
 
         pipeline = SyntheticLMPipeline(

@@ -133,7 +133,12 @@ def attention(
             qpos, kpos, causal=causal, window=window, kv_length=kv_length
         )
         scores = jnp.where(msk, scores.astype(jnp.float32), NEG_INF)
-        m_blk = jnp.max(scores, axis=-1)
+        # the running max only stabilizes the exponentials (out = acc / l does
+        # not depend on it), so no gradient flows through it — as in
+        # jax.nn.softmax. Differentiating the max instead divides by the count
+        # of entries equal to it, which is 0 (NaN gradients) when the backward
+        # pass recomputes bf16 scores with a different rounding, as on TPU.
+        m_blk = jax.lax.stop_gradient(jnp.max(scores, axis=-1))
         m_new = jnp.maximum(m_run, m_blk)
         corr = jnp.exp(m_run - m_new)
         p = jnp.exp(scores - m_new[..., None])  # (B,Hq,S,block)

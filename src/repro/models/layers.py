@@ -8,6 +8,7 @@ no allocation, (b) real initialized arrays for smoke tests / examples, and
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -63,7 +64,7 @@ def partition_specs(tree, rules: Dict[str, Any]) -> Any:
 
     def axis_product(r) -> int:
         names = (r,) if isinstance(r, str) else tuple(r)
-        return int(jnp.prod(jnp.asarray([sizes.get(n, 1) for n in names]))) if names else 1
+        return math.prod(sizes.get(n, 1) for n in names)
 
     def one(spec: PSpec) -> P:
         out = []

@@ -21,8 +21,6 @@ from typing import Any, Tuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import axis_size
-
 
 def _compress_psum_pod(g, err):
     """Per-pod body: g is this pod's partial gradient (still GSPMD-sharded
@@ -34,7 +32,7 @@ def _compress_psum_pod(g, err):
     q = jnp.clip(jnp.round(g32 / scale), -127, 127).astype(jnp.int8)
     new_err = g32 - q.astype(jnp.float32) * scale  # error feedback
     total = jax.lax.psum(q.astype(jnp.int32), "pod")
-    npod = axis_size("pod")
+    npod = jax.lax.axis_size("pod")
     out = (total.astype(jnp.float32) * scale / npod).astype(g.dtype)
     return out, new_err.astype(err.dtype)
 
@@ -51,35 +49,8 @@ def compress_psum_pod_tree(grads, err_state) -> Tuple[Any, Any]:
 
 def uncompressed_psum_pod_tree(grads) -> Any:
     """Reference path (same structure, f32 wire) for A/B tests."""
-    npod = axis_size("pod")
+    npod = jax.lax.axis_size("pod")
     return jax.tree.map(lambda g: jax.lax.psum(g, "pod") / npod, grads)
-
-
-def compress_sum_chunked(g, err):
-    """GSPMD fallback for jax without partial-manual ``shard_map``: ``g`` and
-    ``err`` carry an explicit pod-chunk leading dim ([n_pod, *param]) sharded
-    over the pod mesh axis; the int32 sum over that dim IS the cross-pod
-    all-reduce once SPMD-partitioned. Same quantization math as
-    ``_compress_psum_pod``."""
-    n_pod = g.shape[0]
-    g32 = g.astype(jnp.float32) + err.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(g32))  # max over pods == the shared decode scale
-    scale = jnp.maximum(amax, 1e-12) / 127.0
-    q = jnp.clip(jnp.round(g32 / scale), -127, 127).astype(jnp.int8)
-    new_err = g32 - q.astype(jnp.float32) * scale
-    total = q.astype(jnp.int32).sum(axis=0)  # cross-pod s32 all-reduce
-    out = (total.astype(jnp.float32) * scale / n_pod).astype(g.dtype)
-    return out, new_err.astype(err.dtype)
-
-
-def compress_sum_chunked_tree(grads, err_state) -> Tuple[Any, Any]:
-    """Tree version of :func:`compress_sum_chunked` (pure GSPMD, no manual
-    axes — usable on jax 0.4.x)."""
-    pairs = jax.tree.map(compress_sum_chunked, grads, err_state)
-    is_pair = lambda x: isinstance(x, tuple)
-    synced = jax.tree.map(lambda p: p[0], pairs, is_leaf=is_pair)
-    new_err = jax.tree.map(lambda p: p[1], pairs, is_leaf=is_pair)
-    return synced, new_err
 
 
 def init_error_state(params, dtype=jnp.float32):
@@ -88,9 +59,3 @@ def init_error_state(params, dtype=jnp.float32):
 
 def abstract_error_state(params, dtype=jnp.float32):
     return jax.tree.map(lambda p: jax.ShapeDtypeStruct(p.shape, dtype), params)
-
-
-def abstract_chunked_error_state(params, n_pod: int, dtype=jnp.float32):
-    return jax.tree.map(
-        lambda p: jax.ShapeDtypeStruct((n_pod,) + tuple(p.shape), dtype), params
-    )

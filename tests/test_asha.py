@@ -206,17 +206,17 @@ def test_hung_rung0_trial_killed_on_scaled_deadline():
         FunctionEvaluator(_hang), isolation="subprocess", max_workers=1,
         timeout_s=8.0,
     ) as s:
-        t0 = time.monotonic()
         s.submit({"x": 1}, fidelity=0.25)
         done = []
         while not done:
             done = s.poll(timeout=10.0)
-        wall = time.monotonic() - t0
         (_, trial), = done
         assert trial.timed_out and not trial.ok
         assert trial.fidelity == 0.25
-        assert "2" in trial.error  # scaled 2s deadline, not the 8s full one
-        assert wall < 6.0, f"rung-0 kill took {wall:.1f}s (full deadline?)"
+        # scaled 2s deadline, not the 8s full one. wall_s runs from dispatch
+        # to a warm worker, so worker spawn and imports are not in it
+        assert "exceeded hard deadline 2.0s" in trial.error, trial.error
+        assert 2.0 <= trial.wall_s < 8.0, trial.wall_s
 
 
 # ------------------------------------------- equal-fidelity incumbent rules

@@ -1,5 +1,7 @@
 """XLA blockwise attention vs naive oracle: shape/dtype/mask sweeps, dynamic
 (traced) sliding windows, decode path with kv_length masking."""
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -63,3 +65,29 @@ def test_decode_kv_length_mask():
     v2 = v.at[:, valid:].set(-1e3)
     out2 = attention(q, k2, v2, q_positions=pos, kv_length=kv_len)
     assert jnp.max(jnp.abs(out - out2)) < 1e-6
+
+
+@pytest.mark.parametrize("s,t,causal", [
+    (257, 257, True),    # padded last block, causal self-attention
+    (96, 200, False),    # cross-attention: queries over a longer encoder
+])
+def test_blockwise_gradient_matches_reference(s, t, causal):
+    """The blockwise path's gradients equal the naive oracle's, and its
+    backward never differentiates the running max: that derivative divides
+    by the count of scores equal to the max, which is zero — NaN gradients —
+    whenever the backward pass recomputes bf16 scores with other rounding
+    (as XLA does on TPU)."""
+    q, k, v, pos = _mk(1, s, t, 4, 2, 32, jnp.float32)
+
+    def loss(fn, **kw):
+        return lambda q, k, v: jnp.sum(
+            jnp.sin(fn(q, k, v, q_positions=pos, causal=causal, **kw)))
+
+    blockwise = loss(attention, block_kv=64)
+    got = jax.grad(blockwise, argnums=(0, 1, 2))(q, k, v)
+    want = jax.grad(loss(attention_reference), argnums=(0, 1, 2))(q, k, v)
+    for g, w in zip(got, want):
+        assert jnp.max(jnp.abs(g - w)) < 1e-4
+    # the max's derivative compares every score with it: a score-shaped eq
+    grad_jaxpr = str(jax.make_jaxpr(jax.grad(blockwise))(q, k, v))
+    assert not re.search(r"bool\[\d+,\d+,\d+,\d+\] = eq ", grad_jaxpr)

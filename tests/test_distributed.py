@@ -14,12 +14,11 @@ import jax, jax.numpy as jnp
 from repro.configs.base import ShapeConfig, RunConfig
 from repro.configs.archs import get_arch
 from repro.distributed.steps import make_step, init_train_state
-from repro.compat import set_mesh
 from repro.launch.mesh import make_host_mesh
 mesh = make_host_mesh(model_parallel=2, pod=2)
 arch = get_arch("llama3.2-1b", smoke=True)
 shape = ShapeConfig("t", 32, 8, "train")
-with set_mesh(mesh):
+with jax.set_mesh(mesh):
     b = make_step(arch, RunConfig(mesh_model_parallel=2), shape, mesh)
     state = init_train_state(b)
     batch = b.model.make_inputs(shape)
@@ -41,14 +40,13 @@ import jax, jax.numpy as jnp
 from repro.configs.base import ShapeConfig, RunConfig
 from repro.configs.archs import get_arch
 from repro.distributed.steps import make_step, init_train_state
-from repro.compat import set_mesh
 from repro.launch.mesh import make_host_mesh
 mesh = make_host_mesh(model_parallel=2, pod=2)
 arch = get_arch("llama3.2-1b", smoke=True)
 shape = ShapeConfig("t", 32, 8, "train")
 losses = {}
 for comp in ["off", "int8"]:
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         b = make_step(arch, RunConfig(mesh_model_parallel=2, grad_compression=comp), shape, mesh)
         state = init_train_state(b, jax.random.PRNGKey(0))
         batch = b.model.make_inputs(shape, jax.random.PRNGKey(1))
@@ -76,13 +74,12 @@ import jax, jax.numpy as jnp
 from repro.configs.base import ShapeConfig, RunConfig
 from repro.configs.archs import get_arch
 from repro.distributed.steps import make_prefill_step, make_decode_step
-from repro.compat import set_mesh
 from repro.launch.mesh import make_host_mesh
 mesh = make_host_mesh(model_parallel=4)
 for name in ["gemma3-1b", "whisper-tiny"]:
     arch = get_arch(name, smoke=True)
     run = RunConfig(mesh_model_parallel=4)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         pre = make_prefill_step(arch, run, ShapeConfig("p", 32, 4, "prefill"), mesh)
         params = pre.model.init_params(jax.random.PRNGKey(0))
         batch = pre.model.make_inputs(ShapeConfig("p", 32, 4, "prefill"))
@@ -119,3 +116,44 @@ assert cell["tpu_hbm_estimate"]["fits_hbm_16gib"]
 print("CELL_OK", cell["roofline"]["bottleneck"])
 """, devices=512)
     assert "CELL_OK" in out
+
+
+def test_sharded_init_leaves_every_leaf_as_its_spec_says(subproc):
+    """Train state (params + AdamW moments) and serving params are made by
+    a jit whose outputs carry the bundle's shardings: every leaf arrives
+    with its spec's sharding, each device holding only its own shard."""
+    out = subproc("""
+import jax
+from jax.sharding import NamedSharding, PartitionSpec as P
+from repro.configs.base import ShapeConfig, RunConfig
+from repro.configs.archs import get_arch
+from repro.distributed.steps import (
+    init_train_state, make_prefill_step, make_train_step)
+from repro.launch.mesh import make_host_mesh
+mesh = make_host_mesh(model_parallel=2)
+arch = get_arch("llama3.2-1b", smoke=True)
+run = RunConfig(mesh_model_parallel=2)
+
+def check(tree, specs):
+    n_split = 0
+    leaves = jax.tree.leaves(tree)
+    spec_leaves = jax.tree.leaves(specs, is_leaf=lambda x: isinstance(x, P))
+    assert len(leaves) == len(spec_leaves) > 0
+    for x, p in zip(leaves, spec_leaves):
+        want = NamedSharding(mesh, p)
+        assert x.sharding.is_equivalent_to(want, x.ndim), (x.sharding, p)
+        shard = want.shard_shape(x.shape)
+        assert all(s.data.shape == shard for s in x.addressable_shards)
+        n_split += shard != x.shape
+    return n_split
+
+with jax.set_mesh(mesh):
+    train = make_train_step(arch, run, ShapeConfig("t", 32, 4, "train"), mesh)
+    state = init_train_state(train)
+    assert check(state, train.in_shardings[0]) > 0
+    pre = make_prefill_step(arch, run, ShapeConfig("p", 32, 4, "prefill"), mesh)
+    params = pre.init_params(jax.random.PRNGKey(0))
+    assert check(params, pre.in_shardings[0]) > 0
+print("SHARDED_INIT_OK")
+""", devices=4)
+    assert "SHARDED_INIT_OK" in out

@@ -12,7 +12,6 @@ from repro.distributed.steps import init_train_state, make_train_step
 from repro.ft.elastic import plan_mesh_shape
 from repro.ft.monitor import StepTimeMonitor
 from repro.ft.runner import ResilientTrainer, RunnerConfig
-from repro.compat import set_mesh
 from repro.launch.mesh import make_host_mesh
 
 
@@ -21,7 +20,7 @@ def _trainer(tmp_path, fail_at=(), steps=8, sub="a"):
     shape = ShapeConfig("t", 32, 4, "train")
     mesh = make_host_mesh(model_parallel=1)
     run = RunConfig(mesh_model_parallel=1, learning_rate=3e-2)  # fast smoke descent
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         bundle = make_train_step(arch, run, shape, mesh)
         state = init_train_state(bundle)
         pipeline = SyntheticLMPipeline(arch, shape, PipelineConfig(seed=0))
@@ -40,7 +39,7 @@ def test_recovery_is_bit_exact(tmp_path):
     """A run with two injected failures must converge to the identical final
     state as an undisturbed run (deterministic data + restore)."""
     clean, mesh = _trainer(tmp_path, fail_at=(), sub="clean")
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         s_clean = clean.run()
         faulty, _ = _trainer(tmp_path, fail_at=(3, 5), sub="faulty")
         s_faulty = faulty.run()
@@ -51,7 +50,7 @@ def test_recovery_is_bit_exact(tmp_path):
 
 def test_loss_decreases_through_failures(tmp_path):
     tr, mesh = _trainer(tmp_path, fail_at=(4,), steps=10)
-    with set_mesh(mesh):
+    with jax.set_mesh(mesh):
         tr.run()
     losses = [h["loss"] for h in tr.history]
     assert losses[-1] < losses[0]
@@ -62,7 +61,7 @@ def test_too_many_failures_raises(tmp_path):
     tr.cfg.max_restarts = 2
     from repro.ft.runner import FailureError
 
-    with pytest.raises(FailureError), set_mesh(mesh):
+    with pytest.raises(FailureError), jax.set_mesh(mesh):
         tr.run()
 
 

@@ -9,6 +9,7 @@ them to workers by pickle-by-reference.
 import json
 import os
 import pickle
+import sys
 import time
 
 import pytest
@@ -18,6 +19,7 @@ from repro.core.evaluators import (
     WalltimeEvaluator,
     _accepts_fidelity,
 )
+from repro.core import executors
 from repro.core.executors import (
     EvaluatorSpec,
     SubprocessBackend,
@@ -54,7 +56,8 @@ from repro.kernels import (
 
 
 def test_kernel_evaluator_ok_path_returns_finite_time():
-    ev = make_kernel_evaluator("rwkv6", (1, 64, 2, 16), repeats=1)
+    ev = make_kernel_evaluator("rwkv6", (1, 64, 2, 16), repeats=1,
+                               interpret=True)
     t, info = ev(KERNEL_SPACES["rwkv6"].defaults())
     assert t < float("inf")
     assert info["kernel"] == "rwkv6"
@@ -67,7 +70,7 @@ def test_kernel_evaluator_numerics_gate_blocks_fast_wrong_variants():
     """A variant outside tolerance must return the infeasible penalty, not a
     timing — a fast-but-wrong block config can never become the incumbent."""
     ev = make_kernel_evaluator("rwkv6", (1, 64, 2, 16), repeats=1,
-                               tolerance=0.0)  # nothing passes a zero gate
+                               interpret=True, tolerance=0.0)  # nothing passes a zero gate
     t, info = ev(KERNEL_SPACES["rwkv6"].defaults())
     assert t == KernelEvaluator.INFEASIBLE
     assert info["numerics_mismatch"] is True
@@ -75,7 +78,8 @@ def test_kernel_evaluator_numerics_gate_blocks_fast_wrong_variants():
 
 
 def test_kernel_evaluator_fidelity_scales_repeats():
-    ev = make_kernel_evaluator("rwkv6", (1, 64, 2, 16), repeats=4)
+    ev = make_kernel_evaluator("rwkv6", (1, 64, 2, 16), repeats=4,
+                               interpret=True)
     _, full = ev(KERNEL_SPACES["rwkv6"].defaults())
     _, half = ev(KERNEL_SPACES["rwkv6"].defaults(), fidelity=0.5)
     assert full["repeats"] == 4 and "fidelity" not in full
@@ -87,7 +91,7 @@ def test_kernel_evaluator_oversize_blocks_snap_not_crash():
     """Proposals beyond the (padded) sequence are legal: the ops-layer snap
     clamps them, so the search space never produces a hard failure."""
     ev = make_kernel_evaluator("flash_attention", (1, 200, 2, 2, 64),
-                               repeats=1)
+                               repeats=1, interpret=True)
     t, info = ev({"block_q": 1024, "block_kv": 1024})
     assert t < float("inf") and "numerics_mismatch" not in info
 
@@ -95,7 +99,8 @@ def test_kernel_evaluator_oversize_blocks_snap_not_crash():
 def test_kernel_evaluator_spec_round_trips_through_pickle():
     """Subprocess workers rebuild the evaluator from its dotted-path spec;
     device arrays must never ride along in the pickle."""
-    ev = make_kernel_evaluator("ssm_scan", (1, 64, 32, 8), repeats=2, seed=7)
+    ev = make_kernel_evaluator("ssm_scan", (1, 64, 32, 8), repeats=2, seed=7,
+                               interpret=True)
     ev._materialize()
     clone = pickle.loads(pickle.dumps(ev))
     assert clone._data is None  # arrays dropped at the process boundary
@@ -298,8 +303,7 @@ def test_pin_env_tpu_bounds_one_chip_per_process(monkeypatch):
 
 def test_pin_env_cpu_fallback_strips_inherited_device_count(monkeypatch):
     monkeypatch.delenv("CUDA_VISIBLE_DEVICES", raising=False)
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    monkeypatch.delenv("TPU_WORKER_ID", raising=False)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     monkeypatch.setenv(
         "XLA_FLAGS",
         "--xla_foo=1 --xla_force_host_platform_device_count=512")
@@ -310,9 +314,61 @@ def test_pin_env_cpu_fallback_strips_inherited_device_count(monkeypatch):
     assert "--xla_foo=1" in env["XLA_FLAGS"]  # unrelated flags survive
 
 
+@pytest.mark.parametrize("worker_id", [None, "0"])
+def test_pin_env_tpu_host_never_yields_cpu(monkeypatch, worker_id):
+    """With no platform asked for, a host with TPU chips pins each worker to
+    one chip — never to the host CPU."""
+    monkeypatch.delenv("CUDA_VISIBLE_DEVICES", raising=False)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    if worker_id is None:
+        monkeypatch.delenv("TPU_WORKER_ID", raising=False)
+        monkeypatch.setattr(executors, "_tpu_chips_on_host", lambda: 4)
+    else:
+        monkeypatch.setenv("TPU_WORKER_ID", worker_id)
+    envs = [_device_pin_env(slot, 4) for slot in range(4)]
+    for slot, env in enumerate(envs):
+        assert "JAX_PLATFORMS" not in env
+        assert env["TPU_VISIBLE_CHIPS"] == str(slot)
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,1"
+    assert len({env["TPU_PROCESS_PORT"] for env in envs}) == 4
+
+
+def test_pin_env_cpu_only_host_pins_cpu(monkeypatch):
+    monkeypatch.delenv("CUDA_VISIBLE_DEVICES", raising=False)
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.delenv("TPU_WORKER_ID", raising=False)
+    monkeypatch.setattr(executors, "_tpu_chips_on_host", lambda: 0)
+    assert _device_pin_env(1, 2)["JAX_PLATFORMS"] == "cpu"
+
+
 def test_pin_guard_passes_without_jax_or_pin():
     assert _apply_pin_guard(None) is None
     assert _apply_pin_guard({}) is None
+
+
+class _FakeDevice:
+    def __init__(self, platform):
+        self.platform = platform
+
+
+class _FakeJax:
+    def __init__(self, *platforms):
+        self._devices = [_FakeDevice(p) for p in platforms]
+
+    def devices(self):
+        return self._devices
+
+
+def test_pin_guard_checks_count_and_platform(monkeypatch):
+    tpu_pin = {"TPU_VISIBLE_CHIPS": "0"}
+    monkeypatch.setitem(sys.modules, "jax", _FakeJax("tpu"))
+    assert _apply_pin_guard(tpu_pin) is None
+    monkeypatch.setitem(sys.modules, "jax", _FakeJax("cpu"))
+    assert "pinned to 'tpu'" in _apply_pin_guard(tpu_pin)
+    monkeypatch.setitem(sys.modules, "jax", _FakeJax("tpu", "tpu"))
+    assert "sees 2 devices" in _apply_pin_guard(tpu_pin)
+    monkeypatch.setitem(sys.modules, "jax", _FakeJax("cpu"))
+    assert _apply_pin_guard({"JAX_PLATFORMS": "cpu"}) is None
 
 
 def _pin_probe(cfg):

@@ -2,11 +2,16 @@
 background prefetch.
 
 Production shape: each host materializes only its slice of the global batch
-(``host_slice``), assembles a globally-sharded ``jax.Array`` from the local
-shards, and a prefetch thread keeps ``prefetch_depth`` batches in flight so
-the accelerator never waits on the host. The corpus is a seeded zipfian
-stream, so every run (and every restart — see ``state_dict``) is bit-exact
-reproducible; a restart resumes from the same step's batch.
+(``host_slice``) and assembles a globally-sharded ``jax.Array`` from the
+local shards. A prefetch thread synthesizes up to ``prefetch_depth`` host
+batches ahead, overlapping that work with the step; the consumer still
+waits whenever synthesis is slower than a step, and the copy to the device
+(``_to_device``) runs on the consumer's thread, in the step's path. The
+host spans ``repro:data.make_batch`` (producer), ``repro:data.queue_wait``
+and ``repro:data.to_device`` (consumer) time the three parts in a profiler
+trace. The corpus is a seeded zipfian stream, so every run (and every
+restart — see ``state_dict``) is bit-exact reproducible; a restart resumes
+from the same step's batch.
 """
 from __future__ import annotations
 
@@ -20,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig, ShapeConfig
+from repro.obs import span
 
 
 @dataclass
@@ -68,15 +74,16 @@ class SyntheticLMPipeline:
         return batch
 
     def _to_device(self, host_batch: Dict[str, np.ndarray]):
-        if self.mesh is None or self.batch_sharding is None:
-            return {k: jnp.asarray(v) for k, v in host_batch.items()}
-        from jax.sharding import NamedSharding
+        with span("data.to_device"):
+            if self.mesh is None or self.batch_sharding is None:
+                return {k: jnp.asarray(v) for k, v in host_batch.items()}
+            from jax.sharding import NamedSharding
 
-        out = {}
-        for k, v in host_batch.items():
-            sh = NamedSharding(self.mesh, self.batch_sharding[k])
-            out[k] = jax.device_put(v, sh)
-        return out
+            out = {}
+            for k, v in host_batch.items():
+                sh = NamedSharding(self.mesh, self.batch_sharding[k])
+                out[k] = jax.device_put(v, sh)
+            return out
 
     def __iter__(self) -> Iterator[Dict[str, Any]]:
         q: "queue.Queue" = queue.Queue(maxsize=self.cfg.prefetch_depth)
@@ -86,7 +93,9 @@ class SyntheticLMPipeline:
             step = self.step
             while not stop.is_set():
                 try:
-                    q.put(self._host_batch(step), timeout=0.1)
+                    with span("data.make_batch"):
+                        host_batch = self._host_batch(step)
+                    q.put(host_batch, timeout=0.1)
                     step += 1
                 except queue.Full:
                     continue
@@ -95,7 +104,8 @@ class SyntheticLMPipeline:
         t.start()
         try:
             while True:
-                host_batch = q.get()
+                with span("data.queue_wait"):
+                    host_batch = q.get()
                 self.step += 1
                 yield self._to_device(host_batch)
         finally:

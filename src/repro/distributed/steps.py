@@ -185,11 +185,12 @@ def make_train_step(
         return loss, {"ce": loss, "aux": jnp.zeros(())}, grads
 
     def apply_update(state, grads, loss, metrics, new_err=None):
-        grads, gnorm = clip_by_global_norm(grads, run.gradient_clip)
-        lr = warmup_cosine(state["step"], peak_lr=run.learning_rate)
-        new_params, new_opt = adamw_update(
-            grads, state["opt"], state["params"], state["step"], lr, cfg
-        )
+        with jax.named_scope("optimizer"):
+            grads, gnorm = clip_by_global_norm(grads, run.gradient_clip)
+            lr = warmup_cosine(state["step"], peak_lr=run.learning_rate)
+            new_params, new_opt = adamw_update(
+                grads, state["opt"], state["params"], state["step"], lr, cfg
+            )
         new_state = {
             "params": new_params,
             "opt": new_opt,

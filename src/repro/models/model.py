@@ -141,36 +141,42 @@ class Model:
     # ----------------------------------------------------------------- forward
 
     def _embed_inputs(self, params, batch, ctx: tfm.Ctx):
-        """Token embeddings + modality-frontend substitution."""
+        """Token embeddings + modality-frontend substitution (named scope
+        ``embed``)."""
         arch = self.arch
         cd = ctx.compute_dtype
         tokens = batch["tokens"]
-        if self.run.embed_impl == "one_hot" and ctx.mode == "train":
-            # iota one-hot matmul: the vocab axis stays sharded and the
-            # backward pass is a matmul (no scatter-add into the table).
-            onehot = jax.nn.one_hot(tokens, arch.padded_vocab, dtype=cd)
-            x = jnp.einsum("bsv,vd->bsd", onehot, params["embed"].astype(cd))
-        else:
-            x = params["embed"].astype(cd)[tokens]
-        x = x * jnp.asarray(arch.d_model, cd) ** 0.5 if arch.tie_embeddings else x
-        if arch.frontend == "vision" and "patches" in batch:
-            p = batch["patches"].astype(cd)  # (B, P, D) precomputed (stub)
-            x = jax.lax.dynamic_update_slice(x, p, (0, 0, 0))
-        return x
+        with jax.named_scope("embed"):
+            if self.run.embed_impl == "one_hot" and ctx.mode == "train":
+                # iota one-hot matmul: the vocab axis stays sharded and the
+                # backward pass is a matmul (no scatter-add into the table).
+                onehot = jax.nn.one_hot(tokens, arch.padded_vocab, dtype=cd)
+                x = jnp.einsum("bsv,vd->bsd", onehot, params["embed"].astype(cd))
+            else:
+                x = params["embed"].astype(cd)[tokens]
+            x = x * jnp.asarray(arch.d_model, cd) ** 0.5 if arch.tie_embeddings else x
+            if arch.frontend == "vision" and "patches" in batch:
+                p = batch["patches"].astype(cd)  # (B, P, D) precomputed (stub)
+                x = jax.lax.dynamic_update_slice(x, p, (0, 0, 0))
+            return x
 
     def _encode(self, params, batch, ctx: tfm.Ctx):
-        frames = batch["frames"].astype(ctx.compute_dtype)  # (B, F, D) stub
-        pos = tfm.sinusoidal_positions(frames.shape[1], self.arch.d_model, frames.dtype)
-        enc = tfm.apply_encoder(params["encoder"], frames + pos[None], ctx)
-        return rms_norm(enc, params["enc_final_norm"], self.arch.norm_eps)
+        """The audio encoder over the frames (named scope ``encoder``)."""
+        with jax.named_scope("encoder"):
+            frames = batch["frames"].astype(ctx.compute_dtype)  # (B, F, D) stub
+            pos = tfm.sinusoidal_positions(frames.shape[1], self.arch.d_model, frames.dtype)
+            enc = tfm.apply_encoder(params["encoder"], frames + pos[None], ctx)
+            return rms_norm(enc, params["enc_final_norm"], self.arch.norm_eps)
 
     def _logits(self, params, x, ctx: tfm.Ctx):
         """Logits stay in compute dtype (bf16): the CE converts to f32 inside
-        its (fusable) reductions, avoiding a materialized f32 (B,S,V) buffer."""
+        its (fusable) reductions, avoiding a materialized f32 (B,S,V) buffer.
+        Named scope ``logits``."""
         arch = self.arch
         table = params["embed"] if arch.tie_embeddings else params["unembed"]
-        logits = jnp.einsum("bsd,vd->bsv", x, table.astype(ctx.compute_dtype))
-        return softcap(logits, arch.final_logit_softcap)
+        with jax.named_scope("logits"):
+            logits = jnp.einsum("bsd,vd->bsv", x, table.astype(ctx.compute_dtype))
+            return softcap(logits, arch.final_logit_softcap)
 
     def _cast_params(self, params, ctx: tfm.Ctx):
         """Pre-cast the whole tree to compute dtype ONCE, outside the layer
@@ -220,12 +226,13 @@ class Model:
         x = self._embed_inputs(params, batch, ctx)
         x, aux, _ = self._backbone(params, x, ctx)
         logits = self._logits(params, x, ctx)
-        labels = batch["labels"]
-        if arch.frontend == "vision":
-            # vision positions carry no next-token target
-            labels = jnp.where(positions < arch.frontend_seq, -1, labels)
-        ce = cross_entropy(logits, labels, arch.vocab_size)
-        loss = ce + AUX_LOSS_WEIGHT * aux
+        with jax.named_scope("loss"):
+            labels = batch["labels"]
+            if arch.frontend == "vision":
+                # vision positions carry no next-token target
+                labels = jnp.where(positions < arch.frontend_seq, -1, labels)
+            ce = cross_entropy(logits, labels, arch.vocab_size)
+            loss = ce + AUX_LOSS_WEIGHT * aux
         return loss, {"ce": ce, "aux": aux}
 
     # ------------------------------------------------------------------- serve

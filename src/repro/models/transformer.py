@@ -268,7 +268,18 @@ def _dequantize_kv(q, scale, dtype):
 
 def _attn_sublayer(p, h, ctx: Ctx, *, window, cache, prefix="", cross=False,
                    causal=True):
-    """h: normed input (B,S,D). Returns (out (B,S,D), new_cache)."""
+    """h: normed input (B,S,D). Returns (out (B,S,D), new_cache).
+
+    Named scopes (device time in a profiler trace): ``attn`` or ``xattn``
+    around the whole sublayer; inside, ``qkv`` (projections, RoPE),
+    ``kv_cache`` (cache reads and writes, int8 quantize/dequantize), ``sdpa``
+    (the ``attention`` call) and ``out`` (output projection)."""
+    with jax.named_scope("xattn" if cross else "attn"):
+        return _attn_scoped(p, h, ctx, window=window, cache=cache, prefix=prefix,
+                            cross=cross, causal=causal)
+
+
+def _attn_scoped(p, h, ctx: Ctx, *, window, cache, prefix, cross, causal):
     arch, run = ctx.arch, ctx.run
     b, s, d = h.shape
     dh = arch.resolved_head_dim
@@ -283,99 +294,111 @@ def _attn_sublayer(p, h, ctx: Ctx, *, window, cache, prefix="", cross=False,
             y = y + bias.astype(cd)
         return y.reshape(b, -1, n_h, dh)
 
-    q = proj("wq", h, hq)
-    q = ctx.shard(q, ("act_batch", "act_seq", "act_heads", None))
+    def out_proj(o):
+        with jax.named_scope("out"):
+            return jnp.einsum("bse,ed->bsd", o.reshape(b, s, hq * dh),
+                              p[prefix + "wo"].astype(cd))
+
+    with jax.named_scope("qkv"):
+        q = proj("wq", h, hq)
+        q = ctx.shard(q, ("act_batch", "act_seq", "act_heads", None))
     new_cache = dict(cache) if cache is not None else None
 
     if cross:
         # Cross-attention over the (fixed) encoder sequence: K/V computed from
         # the encoder output at train/prefill time and cached for decode.
         if ctx.mode == "decode":
-            k = cache["ck"].astype(cd)
-            v = cache["cv"].astype(cd)
+            with jax.named_scope("kv_cache"):
+                k = cache["ck"].astype(cd)
+                v = cache["cv"].astype(cd)
         else:
-            enc = ctx.enc_out.astype(cd)
-            k = proj("wk", enc, hkv)
-            v = proj("wv", enc, hkv)
+            with jax.named_scope("qkv"):
+                enc = ctx.enc_out.astype(cd)
+                k = proj("wk", enc, hkv)
+                v = proj("wv", enc, hkv)
             if new_cache is not None:
-                new_cache["ck"] = k.astype(jnp.bfloat16)
-                new_cache["cv"] = v.astype(jnp.bfloat16)
-        out = attention(
-            q, k, v, q_positions=ctx.positions, kv_length=None, causal=False,
-            window=0, softcap_val=0.0, block_kv=run.attn_block_kv, impl="xla",
-            interpret=ctx.interpret,
-        )
-        out = ctx.shard(out, ("act_batch", "act_seq", "act_heads", None))
-        out = jnp.einsum("bse,ed->bsd", out.reshape(b, s, hq * dh),
-                         p[prefix + "wo"].astype(cd))
-        return out, new_cache
+                with jax.named_scope("kv_cache"):
+                    new_cache["ck"] = k.astype(jnp.bfloat16)
+                    new_cache["cv"] = v.astype(jnp.bfloat16)
+        with jax.named_scope("sdpa"):
+            out = attention(
+                q, k, v, q_positions=ctx.positions, kv_length=None, causal=False,
+                window=0, softcap_val=0.0, block_kv=run.attn_block_kv, impl="xla",
+                interpret=ctx.interpret,
+            )
+            out = ctx.shard(out, ("act_batch", "act_seq", "act_heads", None))
+        return out_proj(out), new_cache
 
-    k = proj("wk", h, hkv)
-    v = proj("wv", h, hkv)
-    q = rotary_embedding(q, ctx.positions, arch.rope_theta)
-    k = rotary_embedding(k, ctx.positions, arch.rope_theta)
-    k = ctx.shard(k, ("act_batch", "act_seq", "kv_heads", None))
-    v = ctx.shard(v, ("act_batch", "act_seq", "kv_heads", None))
+    with jax.named_scope("qkv"):
+        k = proj("wk", h, hkv)
+        v = proj("wv", h, hkv)
+        q = rotary_embedding(q, ctx.positions, arch.rope_theta)
+        k = rotary_embedding(k, ctx.positions, arch.rope_theta)
+        k = ctx.shard(k, ("act_batch", "act_seq", "kv_heads", None))
+        v = ctx.shard(v, ("act_batch", "act_seq", "kv_heads", None))
 
     k_scale = v_scale = None
     kv_len = None
-    if ctx.mode == "decode":
-        # Insert the new token's K/V at position cache_len, attend over prefix.
-        pos = ctx.cache_len
-        if run.kv_cache_dtype == "int8":
-            kq, ks = _quantize_kv(k)
-            vq, vs = _quantize_kv(v)
-            new_cache["k"] = jax.lax.dynamic_update_slice(cache["k"], kq, (0, pos, 0, 0))
-            new_cache["v"] = jax.lax.dynamic_update_slice(cache["v"], vq, (0, pos, 0, 0))
-            new_cache["ks"] = jax.lax.dynamic_update_slice(cache["ks"], ks, (0, pos, 0))
-            new_cache["vs"] = jax.lax.dynamic_update_slice(cache["vs"], vs, (0, pos, 0))
-            k_scale, v_scale = new_cache["ks"], new_cache["vs"]
-        else:
-            new_cache["k"] = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0)
-            )
-            new_cache["v"] = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0)
-            )
-        k_use, v_use = new_cache["k"], new_cache["v"]
-        kv_len = jnp.full((b,), pos + 1, jnp.int32)
-    else:
-        if ctx.mode == "prefill":
+    with jax.named_scope("kv_cache"):
+        if ctx.mode == "decode":
+            # Insert the new token's K/V at position cache_len, attend over prefix.
+            pos = ctx.cache_len
             if run.kv_cache_dtype == "int8":
                 kq, ks = _quantize_kv(k)
                 vq, vs = _quantize_kv(v)
-                new_cache = {"k": kq, "v": vq, "ks": ks, "vs": vs}
+                new_cache["k"] = jax.lax.dynamic_update_slice(cache["k"], kq, (0, pos, 0, 0))
+                new_cache["v"] = jax.lax.dynamic_update_slice(cache["v"], vq, (0, pos, 0, 0))
+                new_cache["ks"] = jax.lax.dynamic_update_slice(cache["ks"], ks, (0, pos, 0))
+                new_cache["vs"] = jax.lax.dynamic_update_slice(cache["vs"], vs, (0, pos, 0))
+                k_scale, v_scale = new_cache["ks"], new_cache["vs"]
             else:
-                new_cache = {"k": k.astype(jnp.bfloat16), "v": v.astype(jnp.bfloat16)}
-        k_use, v_use = k, v
+                new_cache["k"] = jax.lax.dynamic_update_slice(
+                    cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0)
+                )
+                new_cache["v"] = jax.lax.dynamic_update_slice(
+                    cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0)
+                )
+            k_use, v_use = new_cache["k"], new_cache["v"]
+            kv_len = jnp.full((b,), pos + 1, jnp.int32)
+        else:
+            if ctx.mode == "prefill":
+                if run.kv_cache_dtype == "int8":
+                    kq, ks = _quantize_kv(k)
+                    vq, vs = _quantize_kv(v)
+                    new_cache = {"k": kq, "v": vq, "ks": ks, "vs": vs}
+                else:
+                    new_cache = {"k": k.astype(jnp.bfloat16), "v": v.astype(jnp.bfloat16)}
+            k_use, v_use = k, v
 
-    if k_scale is not None:
-        k_use = _dequantize_kv(k_use, k_scale, cd)
-        v_use = _dequantize_kv(v_use, v_scale, cd)
-    elif k_use.dtype != cd:
-        k_use = k_use.astype(cd)
-        v_use = v_use.astype(cd)
+        if k_scale is not None:
+            k_use = _dequantize_kv(k_use, k_scale, cd)
+            v_use = _dequantize_kv(v_use, v_scale, cd)
+        elif k_use.dtype != cd:
+            k_use = k_use.astype(cd)
+            v_use = v_use.astype(cd)
 
-    out = attention(
-        q, k_use, v_use, q_positions=ctx.positions, kv_length=kv_len,
-        causal=causal, window=window, softcap_val=arch.attn_logit_softcap,
-        block_kv=run.attn_block_kv,
-        impl=run.attention_impl if ctx.mode != "decode" else "xla",
-        interpret=ctx.interpret, unroll=not run.scan_layers,
-    )
-    out = ctx.shard(out, ("act_batch", "act_seq", "act_heads", None))
-    out = jnp.einsum("bse,ed->bsd", out.reshape(b, s, hq * dh), p[prefix + "wo"].astype(cd))
-    return out, new_cache
+    with jax.named_scope("sdpa"):
+        out = attention(
+            q, k_use, v_use, q_positions=ctx.positions, kv_length=kv_len,
+            causal=causal, window=window, softcap_val=arch.attn_logit_softcap,
+            block_kv=run.attn_block_kv,
+            impl=run.attention_impl if ctx.mode != "decode" else "xla",
+            interpret=ctx.interpret, unroll=not run.scan_layers,
+        )
+        out = ctx.shard(out, ("act_batch", "act_seq", "act_heads", None))
+    return out_proj(out), new_cache
 
 
 def _ffn_sublayer(p, h, desc: LayerDesc, ctx: Ctx):
-    """Returns (out, aux_loss)."""
+    """Returns (out, aux_loss). Named scope ``moe`` or ``mlp``."""
     if desc.is_moe:
-        out, aux = moe_mod.moe_apply(
-            p["moe"], h, ctx.arch, ctx.compute_dtype, shard=ctx.shard
-        )
+        with jax.named_scope("moe"):
+            out, aux = moe_mod.moe_apply(
+                p["moe"], h, ctx.arch, ctx.compute_dtype, shard=ctx.shard
+            )
         return out, aux
-    return gated_mlp(p["mlp"], h, ctx.compute_dtype), 0.0
+    with jax.named_scope("mlp"):
+        return gated_mlp(p["mlp"], h, ctx.compute_dtype), 0.0
 
 
 def apply_layer(p, x, desc: LayerDesc, ctx: Ctx, *, window, cache):
@@ -393,29 +416,31 @@ def apply_layer(p, x, desc: LayerDesc, ctx: Ctx, *, window, cache):
                 "shift_t": jnp.zeros((b, d), x.dtype),
                 "shift_c": jnp.zeros((b, d), x.dtype),
             }
-        h = rms_norm(x, p["ln1"], eps)
-        out, new_shift_t, new_wkv = rwkv_mod.time_mix(
-            p["tmix"], h, cache["shift_t"].astype(x.dtype), cache["wkv"], arch,
-            chunk=min(ctx.run.attn_block_kv, max(x.shape[1], 16)),
-            unroll=not ctx.run.scan_layers,
-        )
-        x = x + out
-        h2 = rms_norm(x, p["ln2"], eps)
-        out2, new_shift_c = rwkv_mod.channel_mix(p["cmix"], h2, cache["shift_c"].astype(x.dtype))
-        x = x + out2
-        new_cache = {"wkv": new_wkv, "shift_t": new_shift_t.astype(cache["shift_t"].dtype),
-                     "shift_c": new_shift_c.astype(cache["shift_c"].dtype)}
+        with jax.named_scope("rwkv"):
+            h = rms_norm(x, p["ln1"], eps)
+            out, new_shift_t, new_wkv = rwkv_mod.time_mix(
+                p["tmix"], h, cache["shift_t"].astype(x.dtype), cache["wkv"], arch,
+                chunk=min(ctx.run.attn_block_kv, max(x.shape[1], 16)),
+                unroll=not ctx.run.scan_layers,
+            )
+            x = x + out
+            h2 = rms_norm(x, p["ln2"], eps)
+            out2, new_shift_c = rwkv_mod.channel_mix(p["cmix"], h2, cache["shift_c"].astype(x.dtype))
+            x = x + out2
+            new_cache = {"wkv": new_wkv, "shift_t": new_shift_t.astype(cache["shift_t"].dtype),
+                         "shift_c": new_shift_c.astype(cache["shift_c"].dtype)}
         return x, aux, (new_cache if ctx.mode != "train" else None)
 
     if desc.kind == "mamba":
-        h = rms_norm(x, p["ln1"], eps)
-        if ctx.mode == "decode":
-            out, new_cache = mamba_mod.mamba_decode_step(p["mamba"], h, cache, arch)
-        else:
-            out, new_cache = mamba_mod.mamba_forward(
-                p["mamba"], h, arch, return_cache=(ctx.mode == "prefill")
-            )
-        x = x + out
+        with jax.named_scope("mamba"):
+            h = rms_norm(x, p["ln1"], eps)
+            if ctx.mode == "decode":
+                out, new_cache = mamba_mod.mamba_decode_step(p["mamba"], h, cache, arch)
+            else:
+                out, new_cache = mamba_mod.mamba_forward(
+                    p["mamba"], h, arch, return_cache=(ctx.mode == "prefill")
+                )
+            x = x + out
     else:
         h = rms_norm(x, p["ln1"], eps)
         out, new_cache = _attn_sublayer(p["attn"], h, ctx, window=window, cache=cache)
@@ -526,7 +551,8 @@ def apply_encoder(params, x, ctx: Ctx):
         )
         carry = carry + out
         h2 = rms_norm(carry, gparams["l0"]["ln2"], arch.norm_eps)
-        carry = carry + gated_mlp(gparams["l0"]["mlp"], h2, enc_ctx.compute_dtype)
+        with jax.named_scope("mlp"):
+            carry = carry + gated_mlp(gparams["l0"]["mlp"], h2, enc_ctx.compute_dtype)
         return carry, None
 
     x, _ = jax.lax.scan(body, x, params, unroll=not ctx.run.scan_layers)

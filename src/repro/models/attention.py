@@ -6,13 +6,17 @@ than O(S·T) — required for the 32k prefill dry-runs — and (b) the tunable
 ``attn_block_kv`` knob is meaningful on both paths. The Pallas path (TPU
 target) lives in ``repro.kernels.flash_attention``.
 
-GQA is realised by repeating K/V to the full query-head count *inside each KV
-block*, so all activation tensors carry a flat head axis that is divisible by
-the model-parallel degree whenever ``num_heads`` is (the (Hkv, G) factored
-layout cannot be sharded 16-way when both factors are < 16, e.g. qwen2's
-8 × 8). ``window`` may be a traced per-layer scalar (≤ 0 means full context),
-which lets local/global alternating stacks (gemma2/gemma3) share one scanned
-layer body.
+GQA takes one of two forms, by branch. The single-shot branch (decode, and
+contexts of at most ``block_kv``) groups the query heads over their KV head
+(``(B, S, Hkv, G, Dh)`` against ``(B, T, Hkv, Dh)``), so each KV head is read
+once per step and no repeated copy of K/V is made. The blockwise branch repeats
+K/V to the full query-head count *inside each KV block*, so its activations
+carry a flat head axis that is divisible by the model-parallel degree whenever
+``num_heads`` is (the (Hkv, G) factored layout cannot be sharded 16-way when
+both factors are < 16, e.g. qwen2's 8 × 8). With G == 1 (MHA) both branches
+use K/V as they are. ``window`` may be a traced per-layer scalar (≤ 0 means
+full context), which lets local/global alternating stacks (gemma2/gemma3)
+share one scanned layer body.
 """
 from __future__ import annotations
 
@@ -103,15 +107,17 @@ def attention(
 
     if s == 1 or t <= block_kv:
         # Decode / short context: single-shot masked attention (linear in T).
-        kf, vf = expand(k), expand(v)
-        scores = jnp.einsum("bshd,bthd->bhst", qs, kf)
+        # Each KV head serves its group of G query heads as it is: scores
+        # (B,Hkv,G,S,T), the mask broadcast over G.
+        qs = qs.reshape(b, s, hkv, g, dh)
+        scores = jnp.einsum("bskgd,btkd->bkgst", qs, k)
         scores = _softcap(scores, softcap_val)
         kpos = jnp.arange(t)[None, None, None, :]
         m = _mask(qpos, kpos, causal=causal, window=window, kv_length=kv_length)
-        scores = jnp.where(m, scores.astype(jnp.float32), NEG_INF)
+        scores = jnp.where(m[:, :, None], scores.astype(jnp.float32), NEG_INF)
         probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bhst,bthd->bshd", probs, vf)
-        return out
+        out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
+        return out.reshape(b, s, hq, dh)
 
     # Blockwise online-softmax over KV blocks.
     n_blocks = -(-t // block_kv)

@@ -67,12 +67,16 @@ def attention(
     impl: str = "xla",
     interpret: bool = False,
     unroll: bool = False,
+    new_kv=None,
 ):
     """Grouped-query attention.
 
     q: (B, S, Hq, Dh); k, v: (B, T, Hkv, Dh). ``q_positions``: (B, S) global
     positions of the queries (supports decode with cache offset).
     ``kv_length``: optional (B,) valid KV prefix length (decode caches).
+    ``new_kv``: optional decode-step (k, v), each (B, 1, Hkv, Dh): the
+    query's own K/V, attended beside ``k``/``v`` as one more position, so a
+    step reads its cache as it came in and need not write into it first.
     ``window``: 0 = full; > 0 = sliding window; may be a traced scalar
     (then ≤ 0 means full). Returns (B, S, Hq, Dh).
     """
@@ -81,6 +85,8 @@ def attention(
     g = hq // hkv
     scale = dh**-0.5
     qs = q * scale
+    if new_kv is not None and (s != 1 or impl != "xla"):
+        raise ValueError("new_kv is for single-token decode on the XLA path")
 
     if impl == "pallas":
         from repro.kernels.flash_attention import ops as fa_ops
@@ -115,9 +121,22 @@ def attention(
         kpos = jnp.arange(t)[None, None, None, :]
         m = _mask(qpos, kpos, causal=causal, window=window, kv_length=kv_length)
         scores = jnp.where(m[:, :, None], scores.astype(jnp.float32), NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
-        out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
-        return out.reshape(b, s, hq, dh)
+        if new_kv is None:
+            probs = jax.nn.softmax(scores, axis=-1).astype(q.dtype)
+            out = jnp.einsum("bkgst,btkd->bskgd", probs, v)
+            return out.reshape(b, s, hq, dh)
+        # the current token's K/V, at the query's own position, beside the
+        # cache's positions: one softmax over both
+        k_new, v_new = new_kv  # (B, 1, Hkv, Dh)
+        s_new = jnp.einsum("bskgd,bskd->bkgs", qs, k_new)[..., None]
+        s_new = _softcap(s_new, softcap_val).astype(jnp.float32)
+        top = jnp.maximum(jnp.max(scores, -1, keepdims=True), s_new)
+        e, e_new = jnp.exp(scores - top), jnp.exp(s_new - top)
+        denom = jnp.sum(e, -1, keepdims=True) + e_new
+        probs, p_new = (e / denom).astype(q.dtype), (e_new / denom).astype(q.dtype)
+        out = jnp.einsum("bkgst,btkd->bskgd", probs, v, preferred_element_type=jnp.float32)
+        out = out + jnp.einsum("bkgst,bskd->bskgd", p_new, v_new, preferred_element_type=jnp.float32)
+        return out.astype(q.dtype).reshape(b, s, hq, dh)
 
     # Blockwise online-softmax over KV blocks.
     n_blocks = -(-t // block_kv)

@@ -16,7 +16,8 @@ meaning full attention), so local and global layers share one traced body.
 Three modes:
   - ``train``   — full sequence, no caches.
   - ``prefill`` — full sequence, emits per-layer caches (KV / SSM / RWKV).
-  - ``decode``  — single token, consumes + re-emits caches.
+  - ``decode``  — single token, reads the caches and writes its new token
+    rows (or replaced states) into them in place.
 """
 from __future__ import annotations
 
@@ -266,9 +267,49 @@ def _dequantize_kv(q, scale, dtype):
     return (q.astype(jnp.float32) * scale[..., None]).astype(dtype)
 
 
+# cache leaves a decode step extends by one token row; it replaces the
+# others whole (mamba, rwkv states) or only reads them (cross K/V)
+_ROW_LEAVES = ("k", "v", "ks", "vs")
+
+
+def _write_decode_cache(caches, written, pos):
+    """The cache a decode step returns: the stacked cache tree ``caches``
+    with what its layers ``written`` (stacked alike): the token rows of
+    ``_ROW_LEAVES`` at sequence position ``pos``, the states a layer replaced
+    as they are, and the leaves no layer wrote as they came in. The rows of
+    every layer go in by one in-place update of each (donated) stack (named
+    scope ``kv_cache``)."""
+
+    def leaf(name, stack, new):
+        if new is None:
+            return stack
+        if name not in _ROW_LEAVES:
+            return new.astype(stack.dtype)
+        start = (0, 0, pos) + (0,) * (stack.ndim - 3)  # (group, B, T, ...)
+        return jax.lax.dynamic_update_slice(stack, new.astype(stack.dtype), start)
+
+    with jax.named_scope("kv_cache"):
+        return {
+            lname: {name: leaf(name, stack, (written.get(lname) or {}).get(name))
+                    for name, stack in leaves.items()}
+            for lname, leaves in caches.items()
+        }
+
+
+def _group_cache(caches, gi):
+    """Group ``gi``'s layer caches, read out of the stacks (named scope
+    ``kv_cache``); None without caches."""
+    if caches is None:
+        return None
+    with jax.named_scope("kv_cache"):
+        return jax.tree.map(lambda c: jax.lax.dynamic_index_in_dim(c, gi, keepdims=False), caches)
+
+
 def _attn_sublayer(p, h, ctx: Ctx, *, window, cache, prefix="", cross=False,
                    causal=True):
-    """h: normed input (B,S,D). Returns (out (B,S,D), new_cache).
+    """h: normed input (B,S,D). Returns (out (B,S,D), new_cache): the cache
+    entries the sublayer produced (prefill: its whole K/V; decode: the new
+    token's rows), None where it produced none.
 
     Named scopes (device time in a profiler trace): ``attn`` or ``xattn``
     around the whole sublayer; inside, ``qkv`` (projections, RoPE),
@@ -302,7 +343,7 @@ def _attn_scoped(p, h, ctx: Ctx, *, window, cache, prefix, cross, causal):
     with jax.named_scope("qkv"):
         q = proj("wq", h, hq)
         q = ctx.shard(q, ("act_batch", "act_seq", "act_heads", None))
-    new_cache = dict(cache) if cache is not None else None
+    new_cache = None
 
     if cross:
         # Cross-attention over the (fixed) encoder sequence: K/V computed from
@@ -316,10 +357,9 @@ def _attn_scoped(p, h, ctx: Ctx, *, window, cache, prefix, cross, causal):
                 enc = ctx.enc_out.astype(cd)
                 k = proj("wk", enc, hkv)
                 v = proj("wv", enc, hkv)
-            if new_cache is not None:
+            if ctx.mode == "prefill":
                 with jax.named_scope("kv_cache"):
-                    new_cache["ck"] = k.astype(jnp.bfloat16)
-                    new_cache["cv"] = v.astype(jnp.bfloat16)
+                    new_cache = {"ck": k.astype(jnp.bfloat16), "cv": v.astype(jnp.bfloat16)}
         with jax.named_scope("sdpa"):
             out = attention(
                 q, k, v, q_positions=ctx.positions, kv_length=None, causal=False,
@@ -338,28 +378,23 @@ def _attn_scoped(p, h, ctx: Ctx, *, window, cache, prefix, cross, causal):
         v = ctx.shard(v, ("act_batch", "act_seq", "kv_heads", None))
 
     k_scale = v_scale = None
-    kv_len = None
+    kv_len = new_kv = None
     with jax.named_scope("kv_cache"):
         if ctx.mode == "decode":
-            # Insert the new token's K/V at position cache_len, attend over prefix.
-            pos = ctx.cache_len
+            # The layer's cache holds the positions before cache_len and
+            # attention takes the new token's K/V beside it. Only these rows
+            # leave the layer: ``_write_decode_cache`` puts them in the stacks.
             if run.kv_cache_dtype == "int8":
                 kq, ks = _quantize_kv(k)
                 vq, vs = _quantize_kv(v)
-                new_cache["k"] = jax.lax.dynamic_update_slice(cache["k"], kq, (0, pos, 0, 0))
-                new_cache["v"] = jax.lax.dynamic_update_slice(cache["v"], vq, (0, pos, 0, 0))
-                new_cache["ks"] = jax.lax.dynamic_update_slice(cache["ks"], ks, (0, pos, 0))
-                new_cache["vs"] = jax.lax.dynamic_update_slice(cache["vs"], vs, (0, pos, 0))
-                k_scale, v_scale = new_cache["ks"], new_cache["vs"]
+                new_cache = {"k": kq, "v": vq, "ks": ks, "vs": vs}
+                k_scale, v_scale = cache["ks"], cache["vs"]
+                new_kv = (_dequantize_kv(kq, ks, cd), _dequantize_kv(vq, vs, cd))
             else:
-                new_cache["k"] = jax.lax.dynamic_update_slice(
-                    cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0)
-                )
-                new_cache["v"] = jax.lax.dynamic_update_slice(
-                    cache["v"], v.astype(cache["v"].dtype), (0, pos, 0, 0)
-                )
-            k_use, v_use = new_cache["k"], new_cache["v"]
-            kv_len = jnp.full((b,), pos + 1, jnp.int32)
+                new_cache = {"k": k.astype(cache["k"].dtype), "v": v.astype(cache["v"].dtype)}
+                new_kv = (new_cache["k"].astype(cd), new_cache["v"].astype(cd))
+            k_use, v_use = cache["k"], cache["v"]
+            kv_len = jnp.full((b,), ctx.cache_len, jnp.int32)
         else:
             if ctx.mode == "prefill":
                 if run.kv_cache_dtype == "int8":
@@ -379,7 +414,7 @@ def _attn_scoped(p, h, ctx: Ctx, *, window, cache, prefix, cross, causal):
 
     with jax.named_scope("sdpa"):
         out = attention(
-            q, k_use, v_use, q_positions=ctx.positions, kv_length=kv_len,
+            q, k_use, v_use, q_positions=ctx.positions, kv_length=kv_len, new_kv=new_kv,
             causal=causal, window=window, softcap_val=arch.attn_logit_softcap,
             block_kv=run.attn_block_kv,
             impl=run.attention_impl if ctx.mode != "decode" else "xla",
@@ -402,7 +437,8 @@ def _ffn_sublayer(p, h, desc: LayerDesc, ctx: Ctx):
 
 
 def apply_layer(p, x, desc: LayerDesc, ctx: Ctx, *, window, cache):
-    """Pre-norm residual layer. Returns (x, aux_loss, new_cache)."""
+    """Pre-norm residual layer. Returns (x, aux_loss, new_cache); in decode,
+    new_cache holds what the layer wrote (K/V rows, or its replaced states)."""
     arch = ctx.arch
     eps = arch.norm_eps
     aux = 0.0
@@ -448,11 +484,12 @@ def apply_layer(p, x, desc: LayerDesc, ctx: Ctx, *, window, cache):
         if desc.cross:
             hx = rms_norm(x, p["lnx"], eps)
             # cross K/V ride in the same per-layer cache dict
-            merged = new_cache if new_cache is not None else (dict(cache) if cache is not None else None)
-            outx, new_cache = _attn_sublayer(
-                p["xattn"], hx, ctx, window=0, cache=merged, prefix="c", cross=True
+            outx, cross_cache = _attn_sublayer(
+                p["xattn"], hx, ctx, window=0, cache=cache, prefix="c", cross=True
             )
             x = x + outx
+            if cross_cache is not None:
+                new_cache = {**new_cache, **cross_cache}
 
     h = rms_norm(x, p["ln2"], eps)
     out, aux = _ffn_sublayer(p, h, desc, ctx)
@@ -475,6 +512,12 @@ def apply_stack(params, x, ctx: Ctx, *, caches=None, windows=None):
 
     params: stacked stack params; caches: stacked cache tree (decode) or None;
     windows: (num_layers,) int32 or None. Returns (x, aux_loss, new_caches).
+
+    Decode reads each group's caches out of ``caches`` and has its layers
+    emit only what they wrote (token rows, replaced states);
+    ``_write_decode_cache`` puts that into ``caches`` after the layers, so no
+    layer's whole cache is rebuilt and no fresh stack is built beside the
+    donated one.
     """
     arch = ctx.arch
     period = structural_period(arch)
@@ -502,14 +545,16 @@ def apply_stack(params, x, ctx: Ctx, *, caches=None, windows=None):
     if ctx.run.scan_layers and n_grp > 1:
         def body(carry, scanned):
             x_c, aux_c = carry
-            gparams, gwin, gcache = scanned
-            x_c, aux, nc = group_body(x_c, gparams, gwin, gcache)
+            gparams, gwin, gi = scanned
+            x_c, aux, nc = group_body(x_c, gparams, gwin, _group_cache(caches, gi))
             return (x_c, aux_c + aux), nc
 
         if ctx.mode == "train":
             body = jax.checkpoint(body, policy=_remat_policy(ctx.run.remat_policy), prevent_cse=True)
-        xs = (params, win_grp, caches)
+        xs = (params, win_grp, None if caches is None else jnp.arange(n_grp))
         (x, aux), new_caches = jax.lax.scan(body, (x, 0.0), xs)
+        if ctx.mode == "decode":
+            new_caches = _write_decode_cache(caches, new_caches, ctx.cache_len)
         return x, aux, new_caches
 
     # Unrolled path (exact per-layer cost analysis; scan_layers=False).
@@ -522,14 +567,15 @@ def apply_stack(params, x, ctx: Ctx, *, caches=None, windows=None):
     new_caches = []
     for gi in range(n_grp):
         gparams = jax.tree.map(lambda a: a[gi], params)
-        gcache = jax.tree.map(lambda a: a[gi], caches) if caches is not None else None
-        x, aux, nc = body_fn(x, gparams, win_grp[gi], gcache)
+        x, aux, nc = body_fn(x, gparams, win_grp[gi], _group_cache(caches, gi))
         aux_total = aux_total + aux
         new_caches.append(nc)
     if new_caches and new_caches[0] is not None:
         new_caches = jax.tree.map(lambda *xs: jnp.stack(xs), *new_caches)
     else:
         new_caches = None
+    if ctx.mode == "decode":
+        new_caches = _write_decode_cache(caches, new_caches, ctx.cache_len)
     return x, aux_total, new_caches
 
 

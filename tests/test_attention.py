@@ -1,7 +1,8 @@
 """XLA attention vs naive oracle: shape/dtype/mask sweeps of the blockwise and
 single-shot branches, dynamic (traced) sliding windows, decode path with
-kv_length masking, grouped-query decode without a K/V repeat (lowered program,
-and under model parallelism)."""
+kv_length masking and with the step's own K/V given beside the cache,
+grouped-query decode without a K/V repeat (lowered program, and under model
+parallelism)."""
 import re
 
 import jax
@@ -91,6 +92,24 @@ def test_decode_kv_length_mask():
     v2 = v.at[:, valid:].set(-1e3)
     out2 = attention(q, k2, v2, q_positions=pos, kv_length=kv_len)
     assert jnp.max(jnp.abs(out - out2)) < 1e-6
+
+
+@pytest.mark.parametrize("hq,hkv", HEADS)
+@pytest.mark.parametrize("window,cap", [(0, 0.0), (16, 0.0), (0, 30.0)])
+def test_decode_new_kv_matches_a_written_cache(hq, hkv, window, cap):
+    """The step's own K/V given beside the cache (``new_kv``) attends as the
+    same K/V written into the cache at ``kv_length`` does; whatever the cache
+    holds from that position on stays unseen."""
+    b, t, dh, pos = 2, 64, 32, 40
+    q, k, v, _ = _mk(b, 1, t, hq, hkv, dh, jnp.float32, seed=5)
+    k_new, v_new = (x[:, pos:pos + 1] for x in _mk(b, 1, t, hq, hkv, dh, jnp.float32, seed=6)[1:3])
+    qpos = jnp.full((b, 1), pos, jnp.int32)
+    kw = dict(q_positions=qpos, window=window, softcap_val=cap)
+    written = attention(q, k.at[:, pos:pos + 1].set(k_new), v.at[:, pos:pos + 1].set(v_new),
+                        kv_length=jnp.full((b,), pos + 1, jnp.int32), **kw)
+    beside = attention(q, k.at[:, pos:].set(1e3), v.at[:, pos:].set(-1e3),
+                       kv_length=jnp.full((b,), pos, jnp.int32), new_kv=(k_new, v_new), **kw)
+    assert jnp.max(jnp.abs(written - beside)) < 2e-5
 
 
 @pytest.mark.parametrize("s,t,causal,hq,hkv,window", [

@@ -1,15 +1,19 @@
-"""The main-path Pallas kernels compile for a TPU v5e chip at real widths.
+"""The main-path Pallas kernels compile for a TPU v5e chip at real widths,
+and the decode step updates its KV cache in place there.
 
 Nothing runs: the TPU compiler that ships with jax compiles for a described,
 unattached v5e chip, and refuses what the chip would refuse (block shapes off
 the (8, 128) tiling, primitives Mosaic cannot lower, too much VMEM). Interpret
 mode checks none of that. Widths: flash attention at llama3.2-1b prefill,
-wkv6 at rwkv6-7b, the selective scan at jamba-1.5-large.
+wkv6 at rwkv6-7b, the selective scan at jamba-1.5-large. The decode step's
+memory is the TPU compiler's: the CPU backend widens a bfloat16 cache to
+float32 as a whole, which hides what the program copies.
 
 The topology is described inside a module fixture — never while a module is
 imported — so every pytest-xdist worker collects the same tests and only the
 worker that runs this file loads the TPU library.
 """
+import dataclasses
 import os
 
 import pytest
@@ -71,3 +75,36 @@ def test_selective_scan_compiles_at_jamba(one_chip, dtype):
     text = _compile(selective_scan, one_chip, (x, dtype), (x, dtype),
                     (bc, dtype), (bc, dtype), ((16384, 16), F32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_decode_step_writes_the_donated_cache_in_place(one_chip, kv_dtype):
+    """A GQA decode step (8 KV heads of 128, batch 8, 2048 positions, 4
+    layers) with its caches donated: the output cache is the input's buffer,
+    and the step's temporaries hold less than one layer stack of K. A step
+    that rebuilt the stacks as the layer scan's outputs needs two."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from repro.configs.archs import get_arch
+    from repro.configs.base import RunConfig, ShapeConfig
+    from repro.distributed.steps import make_decode_step
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh(1, devices=list(one_chip.device_set))
+    arch = dataclasses.replace(
+        get_arch("internvl2-26b", smoke=True), num_layers=4, d_model=1024,
+        num_heads=16, num_kv_heads=8, head_dim=128, d_ff=2048, vocab_size=4096)
+    with jax.set_mesh(mesh):
+        step = make_decode_step(arch, RunConfig(kv_cache_dtype=kv_dtype),
+                                ShapeConfig("d", 2048, 8, "decode"), mesh)
+        shardings = jax.tree.map(lambda p: NamedSharding(mesh, p), step.in_shardings,
+                                 is_leaf=lambda x: isinstance(x, PartitionSpec))
+        args = jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+                            step.abstract_inputs, shardings)
+        memory = step.jit().lower(*args).compile().memory_analysis()
+    caches = step.abstract_inputs[1]
+    k_stack = caches["l0"]["k"].size * caches["l0"]["k"].dtype.itemsize
+    cache_bytes = sum(x.size * x.dtype.itemsize for x in jax.tree.leaves(caches))
+    assert step.donate_argnums == (1,)
+    assert memory.alias_size_in_bytes == cache_bytes
+    assert memory.temp_size_in_bytes < k_stack, (memory.temp_size_in_bytes, k_stack)

@@ -36,11 +36,10 @@ def main(argv=None):
                          "pool; default 1)")
     ap.add_argument("--study", type=Path, default=None,
                     help="Study directory — every table run shares its "
-                         "persistent evaluation cache (created on first use)")
-    ap.add_argument("--cache", type=Path, default=None,
-                    help="legacy persistent JSONL evaluation cache — a warm "
-                         "re-run of the search tables performs no fresh "
-                         "evaluations (ignored when --study is given)")
+                         "persistent evaluation cache (created on first use), "
+                         "so a warm re-run of the search tables performs no "
+                         "fresh evaluations; without it every table measures "
+                         "afresh")
     ap.add_argument("--strategy", default="all",
                     choices=["all", "gsft", "crs", "tpe", "asha"],
                     help="which search strategy's tables to run (default all, "
@@ -55,20 +54,17 @@ def main(argv=None):
                     help="per-trial timeout in seconds (hard SIGKILL under "
                          "--isolation subprocess)")
     args = ap.parse_args(argv)
-    # one validated EngineConfig instead of loose kwargs; --study routes every
-    # table's trials into the study's shared cache. Explicitly-typed flags
-    # overlay the stored engine per-field; untyped flags don't clobber it.
-    from repro.launch.tune import engine_config, engine_overrides, \
-        open_persistent_study
+    # one validated EngineConfig; --study routes every table's trials into
+    # the study's shared cache. Explicitly-typed flags overlay the stored
+    # engine per-field; untyped flags don't clobber it.
+    from repro.core import EngineConfig
+    from repro.launch.tune import engine_overrides, open_persistent_study
 
-    engine = engine_config(args)
-    cache = args.cache
     if args.study:
         study = open_persistent_study(args.study, engine_overrides(args))
-        cache, engine = study.cache_path, study.engine
-    # every TrialScheduler-level knob of the engine flows through (patience/
-    # batch_size are per-run knobs the table functions own themselves)
-    tables.ENGINE.update(cache_path=cache, **engine.scheduler_kwargs())
+        tables.ENGINE.update(engine=study.engine, cache_path=study.cache_path)
+    else:
+        tables.ENGINE.update(engine=EngineConfig(**engine_overrides(args)))
 
     t0 = time.time()
     all_rows = []

@@ -6,21 +6,32 @@ import json
 from pathlib import Path
 from typing import Any, Dict, List
 
-from repro.core import CMPE, tune
-from repro.core.tuner import TuneOutcome
+from repro.core import EngineConfig, Study, TrialScheduler
+from repro.core.study import TuneOutcome
 
 from benchmarks import platforms
 
 RESULTS = Path("results/benchmarks")
 
-# Engine options (TrialScheduler kwargs) applied to every table run —
-# benchmarks.run sets these from --jobs / --cache so the whole suite shares
-# one thread-pool size and one persistent evaluation cache.
-ENGINE: Dict[str, Any] = {}
+# The engine and persistent evaluation cache every table run uses —
+# benchmarks.run sets them from its flags (or --study) so the whole suite
+# shares one thread-pool size and one cache.
+ENGINE: Dict[str, Any] = {"engine": EngineConfig(), "cache_path": None}
 
 
-def _scheduler_opts() -> Dict[str, Any]:
-    return {k: v for k, v in ENGINE.items() if v is not None}
+def _scheduler(ev, platform: str) -> TrialScheduler:
+    """A scheduler on the suite's engine and cache, for tables that time
+    chosen configs one by one."""
+    return TrialScheduler(ev, platform=platform,
+                          cache_path=ENGINE["cache_path"],
+                          **ENGINE["engine"].scheduler_kwargs())
+
+
+def _study(log_name: str) -> Study:
+    """An in-memory Study on the suite's engine and cache whose trial log is
+    ``RESULTS / log_name``."""
+    return Study(engine=ENGINE["engine"], cache_path=ENGINE["cache_path"],
+                 log_path=RESULTS / log_name)
 
 
 def _eval_for(platform: str):
@@ -38,8 +49,8 @@ def _actives(platform: str):
 
 def table_defaults(platform: str) -> List[Dict[str, Any]]:
     ev, space = _eval_for(platform)
-    cmpe = CMPE(ev, platform=platform, **_scheduler_opts())
-    t = cmpe.evaluate(space.defaults(), tag="defaults")
+    with _scheduler(ev, platform) as sched:
+        t = sched.evaluate(space.defaults(), tag="defaults")
     return [{"table": "III" if platform == "wordcount" else "VI",
              "platform": platform, "config": "all-defaults", "time_s": round(t, 4)}]
 
@@ -57,24 +68,24 @@ def one_opt_candidates(space, name):
 
 def table_one_opt(platform: str) -> List[Dict[str, Any]]:
     ev, space = _eval_for(platform)
-    cmpe = CMPE(ev, platform=platform, **_scheduler_opts())
     base = space.defaults()
-    t_default = cmpe.evaluate(base, tag="defaults")
     rows = []
     best_values = {}
-    for p in space.params:
-        best_t, best_v = t_default, p.default
-        for v in one_opt_candidates(space, p.name):
-            t = cmpe.evaluate({**base, p.name: v}, tag=f"one_opt/{p.name}")
-            if t < best_t:
-                best_t, best_v = t, v
-        impr = 100.0 * (t_default - best_t) / t_default
-        best_values[p.name] = best_v
-        rows.append({
-            "table": "IV" if platform == "wordcount" else "VII",
-            "platform": platform, "param": p.name, "tuned_value": best_v,
-            "time_s": round(best_t, 4), "improvement_pct": round(impr, 2),
-        })
+    with _scheduler(ev, platform) as sched:
+        t_default = sched.evaluate(base, tag="defaults")
+        for p in space.params:
+            best_t, best_v = t_default, p.default
+            for v in one_opt_candidates(space, p.name):
+                t = sched.evaluate({**base, p.name: v}, tag=f"one_opt/{p.name}")
+                if t < best_t:
+                    best_t, best_v = t, v
+            impr = 100.0 * (t_default - best_t) / t_default
+            best_values[p.name] = best_v
+            rows.append({
+                "table": "IV" if platform == "wordcount" else "VII",
+                "platform": platform, "param": p.name, "tuned_value": best_v,
+                "time_s": round(best_t, 4), "improvement_pct": round(impr, 2),
+            })
     RESULTS.mkdir(parents=True, exist_ok=True)
     (RESULTS / f"one_opt_{platform}.json").write_text(
         json.dumps({"default_time": t_default, "best_values": best_values,
@@ -91,10 +102,10 @@ def table_all_opt(platform: str) -> List[Dict[str, Any]]:
     if not path.exists():
         table_one_opt(platform)
     prior = json.loads(path.read_text())
-    cmpe = CMPE(ev, platform=platform, **_scheduler_opts())
-    t_default = cmpe.evaluate(space.defaults(), tag="defaults")
     config = space.snap({**space.defaults(), **prior["best_values"]})
-    t = cmpe.evaluate(config, tag="all_opt")
+    with _scheduler(ev, platform) as sched:
+        t_default = sched.evaluate(space.defaults(), tag="defaults")
+        t = sched.evaluate(config, tag="all_opt")
     impr = 100.0 * (t_default - t) / t_default
     return [{"table": "V" if platform == "wordcount" else "VIII",
              "platform": platform, "config": "all-at-individual-optimal",
@@ -106,10 +117,9 @@ def table_all_opt(platform: str) -> List[Dict[str, Any]]:
 
 def table_gsft(platform: str) -> List[Dict[str, Any]]:
     ev, space = _eval_for(platform)
-    out: TuneOutcome = tune(
+    out: TuneOutcome = _study(f"gsft_{platform}.jsonl").optimize(
         platform, "gsft", ev,  # real platform name namespaces the cache
         space=space, active_params=_actives(platform), samples_per_param=3,
-        log_path=RESULTS / f"gsft_{platform}.jsonl", **_scheduler_opts(),
     )
     (RESULTS / f"gsft_{platform}.json").write_text(json.dumps(out.summary(), indent=1, default=str))
     return [{"table": "IX" if platform == "wordcount" else "X",
@@ -125,10 +135,8 @@ def table_gsft(platform: str) -> List[Dict[str, Any]]:
 
 def table_crs(platform: str) -> List[Dict[str, Any]]:
     ev, space = _eval_for(platform)
-    out = tune(
-        platform, "crs", ev,
-        space=space, m=10, k=3, max_rounds=4, seed=0,
-        log_path=RESULTS / f"crs_{platform}.jsonl", **_scheduler_opts(),
+    out = _study(f"crs_{platform}.jsonl").optimize(
+        platform, "crs", ev, space=space, m=10, k=3, max_rounds=4, seed=0,
     )
     (RESULTS / f"crs_{platform}.json").write_text(json.dumps(out.summary(), indent=1, default=str))
     return [{"table": "XI" if platform == "wordcount" else "XII",
@@ -145,14 +153,13 @@ def table_crs(platform: str) -> List[Dict[str, Any]]:
 def table_tpe(platform: str, budget: int = 36) -> List[Dict[str, Any]]:
     """TPE over the full knob set at a GSFT-comparable trial budget.
 
-    ``history=[]`` so that with a shared ``--cache`` the other tables'
-    records can't leak into this table's incumbent — the row must report
-    what TPE itself found with its own budget."""
+    ``history=[]`` so that with a shared ``--study`` cache the other
+    tables' records can't leak into this table's incumbent — the row must
+    report what TPE itself found with its own budget."""
     ev, space = _eval_for(platform)
-    out = tune(
+    out = _study(f"tpe_{platform}.jsonl").optimize(
         platform, "tpe", ev,
         space=space, max_trials=budget, round_size=8, seed=0, history=[],
-        log_path=RESULTS / f"tpe_{platform}.jsonl", **_scheduler_opts(),
     )
     (RESULTS / f"tpe_{platform}.json").write_text(json.dumps(out.summary(), indent=1, default=str))
     return [{"table": "tpe",
@@ -173,20 +180,19 @@ def table_strategy_shootout(platform: str = "wordcount", seed: int = 0) -> List[
     runs with an empty warm-start history so every strategy pays full price.
     Writes ``results/benchmarks/strategy_comparison.json``."""
     ev, space = _eval_for(platform)
-    opts = _scheduler_opts()
 
-    gsft = tune(platform, "gsft", ev, space=space, active_params=_actives(platform),
-                samples_per_param=3,
-                log_path=RESULTS / f"shootout_gsft_{platform}.jsonl", **opts)
+    gsft = _study(f"shootout_gsft_{platform}.jsonl").optimize(
+        platform, "gsft", ev, space=space, active_params=_actives(platform),
+        samples_per_param=3)
     budget = gsft.evaluations
-    crs = tune(platform, "crs", ev, space=space,
-               m=max(4, budget // 4), k=3, max_rounds=4, seed=seed,
-               log_path=RESULTS / f"shootout_crs_{platform}.jsonl", **opts)
-    # budget - 1 proposals: tune() spends one trial on the defaults config,
-    # which gsft.evaluations already counts — totals come out equal
-    tpe = tune(platform, "tpe", ev, space=space, max_trials=budget - 1,
-               round_size=8, seed=seed, history=[],
-               log_path=RESULTS / f"shootout_tpe_{platform}.jsonl", **opts)
+    crs = _study(f"shootout_crs_{platform}.jsonl").optimize(
+        platform, "crs", ev, space=space,
+        m=max(4, budget // 4), k=3, max_rounds=4, seed=seed)
+    # budget - 1 proposals: a session spends one trial on the defaults
+    # config, which gsft.evaluations already counts — totals come out equal
+    tpe = _study(f"shootout_tpe_{platform}.jsonl").optimize(
+        platform, "tpe", ev, space=space, max_trials=budget - 1,
+        round_size=8, seed=seed, history=[])
 
     best_baseline = min(gsft.best_time, crs.best_time)
     rows = []
@@ -249,17 +255,16 @@ def table_asha(platform: str = "wordcount", budget: int = 32,
         ev, space = platforms.wordcount_evaluator(repeats=4)
     else:
         ev, space = _eval_for(platform)
-    opts = _scheduler_opts()
 
-    crs = tune(platform, "crs", ev, space=space,
-               m=max(4, budget // 4), k=3, max_rounds=4, seed=seed,
-               log_path=RESULTS / f"asha_crs_{platform}.jsonl", **opts)
-    tpe = tune(platform, "tpe", ev, space=space, max_trials=budget,
-               round_size=8, seed=seed, history=[],
-               log_path=RESULTS / f"asha_tpe_{platform}.jsonl", **opts)
-    asha = tune(platform, "asha", ev, space=space, max_trials=budget,
-                inner="tpe", eta=4.0, min_fidelity=1.0 / 64.0, seed=seed,
-                log_path=RESULTS / f"asha_asha_{platform}.jsonl", **opts)
+    crs = _study(f"asha_crs_{platform}.jsonl").optimize(
+        platform, "crs", ev, space=space,
+        m=max(4, budget // 4), k=3, max_rounds=4, seed=seed)
+    tpe = _study(f"asha_tpe_{platform}.jsonl").optimize(
+        platform, "tpe", ev, space=space, max_trials=budget,
+        round_size=8, seed=seed, history=[])
+    asha = _study(f"asha_asha_{platform}.jsonl").optimize(
+        platform, "asha", ev, space=space, max_trials=budget,
+        inner="tpe", eta=4.0, min_fidelity=1.0 / 64.0, seed=seed)
 
     # the within-2% verdict compares the *configs* each strategy chose,
     # re-measured back to back under one best-of-8 yardstick — an in-run
@@ -315,7 +320,6 @@ def table_transfer(budget: int = 24, seed: int = 2) -> List[Dict[str, Any]]:
     import tempfile
 
     from repro.apps.wordcount import make_corpus, make_evaluator
-    from repro.core import Study
 
     cell_a, cell_b = "wordcount/wc:1m", "wordcount/wc:2m"
     runs: Dict[str, Dict[str, Any]] = {}
@@ -380,7 +384,6 @@ def table_surrogate(budget: int = 24, seed: int = 3) -> List[Dict[str, Any]]:
     import tempfile
 
     from repro.apps.wordcount import make_corpus, make_evaluator
-    from repro.core import Study
 
     cell_a, cell_b = "wordcount/wc:1m", "wordcount/wc:2m"
     runs: Dict[str, Dict[str, Any]] = {}
@@ -449,7 +452,6 @@ def table_kernels(budget: int = 10, seed: int = 0) -> List[Dict[str, Any]]:
     an incumbent, then default and tuned configs are re-measured back to
     back on the same evaluator and inputs. Rows are merged into
     ``results/benchmarks/strategy_comparison.json``."""
-    from repro.core import Study
     from repro.core.kernel_tune import KERNEL_SPACES, make_kernel_evaluator
 
     shapes = {

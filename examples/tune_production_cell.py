@@ -14,7 +14,7 @@ from pathlib import Path
 
 from repro.configs.archs import ARCH_NAMES, get_arch
 from repro.configs.base import SHAPES
-from repro.core import SPACES, tune
+from repro.core import SPACES, Study
 from repro.core.evaluators import RooflineEvaluator
 
 
@@ -37,9 +37,9 @@ def main():
         if args.algorithm == "gsft"
         else dict(m=8, k=3, max_rounds=3)
     )
-    out = tune(platform, args.algorithm, evaluator,
-               log_path=Path(f"results/examples/tune_{args.arch}_{args.shape}.jsonl"),
-               **kwargs)
+    study = Study(
+        log_path=Path(f"results/examples/tune_{args.arch}_{args.shape}.jsonl"))
+    out = study.optimize(platform, args.algorithm, evaluator, **kwargs)
     print(json.dumps(out.summary(), indent=1, default=str))
 
 

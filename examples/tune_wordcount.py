@@ -7,20 +7,23 @@ wall-clock time, then compare (paper §X/§XI).
 from pathlib import Path
 
 from repro.apps.wordcount import make_evaluator, WORDCOUNT_SPACE
-from repro.core import tune
+from repro.core import Study
 
 
 def main():
     evaluator = make_evaluator()
     log = Path("results/examples/wordcount_tune.jsonl")
+    # in memory, no shared cache: each algorithm pays for its own trials
+    study = Study(log_path=log)
 
-    gsft = tune("train", "gsft", evaluator, space=WORDCOUNT_SPACE, log_path=log,
-                active_params=["replication", "block_tokens", "num_map_tasks"],
-                samples_per_param=3)
-    crs = tune("train", "crs", evaluator, space=WORDCOUNT_SPACE, log_path=log,
-               m=10, k=3, max_rounds=4, seed=0)
-    tpe = tune("train", "tpe", evaluator, space=WORDCOUNT_SPACE, log_path=log,
-               max_trials=40, seed=0)
+    gsft = study.optimize("train", "gsft", evaluator, space=WORDCOUNT_SPACE,
+                          active_params=["replication", "block_tokens",
+                                         "num_map_tasks"],
+                          samples_per_param=3)
+    crs = study.optimize("train", "crs", evaluator, space=WORDCOUNT_SPACE,
+                         m=10, k=3, max_rounds=4, seed=0)
+    tpe = study.optimize("train", "tpe", evaluator, space=WORDCOUNT_SPACE,
+                         max_trials=40, seed=0)
 
     print(f"default execution time : {gsft.default_time*1e3:8.1f} ms")
     print(f"GSFT  best             : {gsft.best_time*1e3:8.1f} ms "

@@ -191,11 +191,8 @@ class RunConfig:
 
     # --- serving knobs ---
     kv_cache_dtype: str = "bfloat16"    # bfloat16 | int8
-    prefill_chunk: int = 0              # 0 = single-shot prefill
-    decode_batch_partition: str = "data"  # data | model | both
     kv_partition: str = "auto"          # auto | heads | sequence
     weight_dtype: str = "bfloat16"      # bfloat16 | int8 (serving weights)
-    max_concurrent_decodes: int = 0     # 0 = batch size (serving scheduler bound)
 
     # --- structural (not tuned; set per environment) ---
     attention_impl: str = "xla"         # xla | pallas

@@ -2,26 +2,25 @@
 
   - ``space``      — the curated 12-train / 11-serve knob tables (§III)
   - ``scheduler``  — TrialScheduler: batched/cached/pruned trial execution
-                     (grown from the paper's CMPE, §VII)
+                     (grown from the paper's CMPE, §VII), plus the trial-log
+                     readers
   - ``executors``  — trial isolation backends: inline threads (soft
                      timeouts) / subprocess workers (hard SIGKILL deadlines)
-  - ``cmpe``       — back-compat serial CMPE facade over the scheduler
-  - ``strategies`` — ask/tell Strategy engine: gsft, crs, hillclimb, tpe
-  - ``grid_finer`` — Algorithm I wrapper: Grid Search with Finer Tuning (§VIII)
-  - ``crs``        — Algorithm II wrapper: Controlled Random Search (§IX)
-  - ``study``      — Study: persistent, resumable tuning sessions + EngineConfig
+  - ``strategies`` — ask/tell Strategy engine: gsft (Algorithm I, Grid
+                     Search with Finer Tuning, §VIII), crs (Algorithm II,
+                     Controlled Random Search, §IX), hillclimb, tpe, random,
+                     asha
+  - ``study``      — Study: persistent, resumable tuning sessions + EngineConfig;
+                     the one way a tuning session is run and stored
   - ``transfer``   — cross-cell transfer: sibling histories, cell similarity,
                      config snapping (the ``--transfer off|warm|prior`` modes)
   - ``surrogate``  — learned cost model over the study cache: ridge
                      regression that pre-ranks TPE acquisition candidates
                      (the ``--surrogate off|rank`` modes)
-  - ``tuner``      — the Admin facade (Figure I) — deprecated shim over Study
   - ``evaluators`` — walltime (paper-faithful) / roofline (AOT) backends
   - ``roofline``   — TPU v5e roofline terms from compiled artifacts
   - ``hlo``        — collective-traffic parser over partitioned HLO
 """
-from repro.core.cmpe import CMPE, best_from_log, read_log
-from repro.core.crs import CRSResult, controlled_random_search
 from repro.core.executors import (
     EvaluatorSpec,
     ExecutionBackend,
@@ -29,13 +28,21 @@ from repro.core.executors import (
     SubprocessBackend,
     make_backend,
 )
-from repro.core.grid_finer import GridResult, grid_search_finer_tuning
-from repro.core.scheduler import Trial, TrialScheduler, config_hash, config_key
+from repro.core.scheduler import (
+    Trial,
+    TrialScheduler,
+    best_from_log,
+    config_hash,
+    config_key,
+    read_log,
+)
 from repro.core.space import SERVE_SPACE, SPACES, TRAIN_SPACE, TunableSpace
 from repro.core.strategies import (
+    CRSResult,
     CRSStrategy,
     CuratedHillclimbStrategy,
     GridFinerStrategy,
+    GridResult,
     HillclimbResult,
     Move,
     Strategy,
@@ -54,10 +61,8 @@ from repro.core.transfer import (
     parse_namespace,
     snap_into_space,
 )
-from repro.core.tuner import tune
 
 __all__ = [
-    "CMPE",
     "EngineConfig",
     "Study",
     "StudyCell",
@@ -94,11 +99,8 @@ __all__ = [
     "best_from_log",
     "config_hash",
     "config_key",
-    "controlled_random_search",
-    "grid_search_finer_tuning",
     "make_backend",
     "make_strategy",
     "read_log",
     "register_strategy",
-    "tune",
 ]

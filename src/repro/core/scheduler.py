@@ -112,10 +112,6 @@ def trial_hash(config: Dict[str, Any], fidelity: float = 1.0) -> str:
     return hashlib.sha256(trial_key(config, fidelity).encode()).hexdigest()[:24]
 
 
-# legacy name used by the old cmpe module
-_key = config_key
-
-
 class TrialScheduler:
     """Batched trial executor with memoization, persistence, and pruning.
 
@@ -578,7 +574,7 @@ class TrialScheduler:
 
     def stats_snapshot(self) -> Dict[str, int]:
         """Point-in-time counters for per-session delta accounting: a Study
-        (or the tune shim) subtracts two snapshots so a shared multi-session
+        subtracts two snapshots so a shared multi-session
         scheduler reports each session's own numbers, never lifetime totals.
         Same counters as :meth:`run_stats` under the outcome-facing name —
         except ``evaluations`` excludes statically-rejected proposals (they

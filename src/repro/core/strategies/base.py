@@ -3,10 +3,9 @@
 A strategy never runs a trial itself. It *asks* for a batch of candidate
 configurations, the :class:`~repro.core.scheduler.TrialScheduler` evaluates
 them (possibly concurrently, possibly from cache), and *tells* the results
-back. Control flow that used to be welded into each algorithm's module
-(`grid_finer`, `crs`, the hillclimb driver) becomes a state machine the one
-shared engine drives — so a new optimizer (Bayesian, online, co-tuning) is a
-new Strategy subclass and nothing else.
+back. Each algorithm (gsft, crs, tpe, the curated hillclimb, ...) is a
+state machine the one shared engine drives — so a new optimizer (Bayesian,
+online, co-tuning) is a new Strategy subclass and nothing else.
 
 Contract
   - ``ask(n)`` returns up to ``n`` configs (all remaining when ``n`` is

@@ -1,8 +1,14 @@
 """Algorithm II — Controlled Random Search (paper §IX, after W.L. Price) as
-an ask/tell strategy. Draw semantics, bound contraction, categorical
-freezing, and the stop rule match the legacy serial implementation exactly:
-all of a round's draws are generated before any result is consumed, so the
-rng stream is identical whether trials run serially or in parallel."""
+an ask/tell strategy.
+
+Inner routine: draw m uniform configurations within the current
+per-parameter bounds, keep the top-k by execution time. Outer loop: contract
+each numeric parameter's bounds to [min, max] of the survivors, freeze
+booleans/categoricals to the survivor majority, re-run, stop when
+round-over-round improvement falls below the threshold. Complexity O(n·m)
+evaluations. All of a round's draws are generated before any result is
+consumed, so the rng stream is identical whether trials run serially or in
+parallel."""
 from __future__ import annotations
 
 import random

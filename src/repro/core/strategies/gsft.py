@@ -1,7 +1,10 @@
 """Algorithm I — Grid Search with Finer Tuning (paper §VIII) as an ask/tell
-strategy. Phase arithmetic is the paper's, unchanged (see the legacy module
-docstring in :mod:`repro.core.grid_finer` for the bound derivation); only the
-control flow moved from a private evaluate loop to the shared engine."""
+strategy. Phase arithmetic is the paper's, unchanged (the finer window's
+bound derivation is in :meth:`GridFinerStrategy._finer_cells`). Complexity
+O(n·m + k) evaluations, as stated in the paper. Run it through
+``Study.optimize(platform, "gsft", evaluator, ...)``, or drive it with
+``TrialScheduler.run``: a serial scheduler evaluates in ask order, a parallel
+one gets the same result."""
 from __future__ import annotations
 
 import itertools

@@ -34,7 +34,7 @@ records, and untagged/explicit ``(config, time_s)`` pairs — also count
 toward ``max_trials``. So a re-run over a complete cache proposes nothing
 (zero fresh evaluations), a re-run over a crashed session's cache resumes
 with exactly the unpaid remainder of its budget, and records another
-strategy left on the platform (a GSFT sweep sharing the same ``--cache``)
+strategy left on the platform (a GSFT sweep earlier in the same study)
 are free model evidence rather than silent budget theft.
 """
 from __future__ import annotations

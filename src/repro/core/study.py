@@ -22,8 +22,9 @@ budget.
 
 Engine knobs (parallel workers, isolation backend, per-trial timeout,
 retries, patience, batch size) live on one validated :class:`EngineConfig`
-instead of a kwarg forest; ``repro.core.tuner.tune`` remains as a thin
-deprecated shim over a throwaway in-memory Study.
+instead of a kwarg forest. A Study is the one way a tuning session is run
+and stored: every CLI driver opens one (``--study DIR``), and a caller that
+needs no storage holds an in-memory ``Study()``.
 """
 from __future__ import annotations
 
@@ -150,7 +151,7 @@ class EngineConfig:
             )
 
     def scheduler_kwargs(self) -> Dict[str, Any]:
-        """Kwargs for :class:`TrialScheduler` (and the ``tune`` shim)."""
+        """Kwargs for :class:`TrialScheduler`."""
         return dict(
             max_workers=self.workers,
             timeout_s=self.timeout_s,
@@ -248,9 +249,9 @@ def run_session(
     """One tuning session on an already-configured scheduler: measure the
     defaults, drive the strategy, report per-session deltas.
 
-    This is the engine path under :meth:`Study.optimize` and the
-    ``tuner.tune`` shim; share one scheduler across calls to share its memo
-    and persistent cache (the multi-cell driver does).
+    This is the engine path under :meth:`Study.optimize` and
+    :meth:`StudyCell.optimize`; share one scheduler across calls to share
+    its memo and persistent cache (a study cell does).
 
     ``siblings``/``transfer`` is the cross-cell channel: when ``transfer``
     is not ``"off"`` and the strategy declares ``supports_transfer``, the
@@ -429,8 +430,8 @@ def _spec_ref(evaluator: Any) -> Optional[Dict[str, Any]]:
 class Study:
     """A persistent, resumable collection of tuning sessions over one storage
     directory (``Study.create`` / ``Study.load`` / ``Study.open``), or an
-    ephemeral in-memory session holder (``Study()`` — what the deprecated
-    ``tune()`` shim uses).
+    ephemeral in-memory session holder (``Study()``, optionally writing its
+    trial log or cache to explicit files).
 
     Storage layout under ``path``:
 

@@ -5,21 +5,19 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 knob changes for one (arch × shape) cell on the production mesh, recording
 hypothesis → change → before → after per iteration.
 
-The curated lists are now ``Move`` sequences fed to the shared
-``CuratedHillclimbStrategy`` + ``TrialScheduler`` engine (same path as
-GSFT/CRS), so a sweep gets the persistent evaluation cache and per-trial
-failure handling for free.
+The curated lists are ``Move`` sequences run as a ``hillclimb`` session of
+a Study (same path as GSFT/CRS), so a sweep gets the persistent evaluation
+cache and per-trial failure handling for free.
 
     PYTHONPATH=src python -m repro.launch.hillclimb --cell qwen2-72b:train_4k
+
+Without ``--study`` the sessions are stored in ``results/studies/hillclimb``.
 """
 import argparse
 import json
 from pathlib import Path
 
-from repro.configs.base import SHAPES
-from repro.configs.archs import get_arch
-from repro.core import SPACES, CuratedHillclimbStrategy, Study, TrialScheduler
-from repro.core.evaluators import RooflineEvaluator
+from repro.core import Study
 
 # (name, hypothesis, overrides) per cell — the napkin math lives in
 # EXPERIMENTS.md §Perf next to the measured outcome.
@@ -55,51 +53,16 @@ CANDIDATES = {
 }
 
 
-def run_cell_sweep(cell: str, out_dir: Path, *, cache_path: Path = None,
-                   scheduler: TrialScheduler = None, study=None):
-    if study is not None and (cache_path is not None or scheduler is not None):
-        raise ValueError(
-            "run_cell_sweep(): cache_path/scheduler would be silently "
-            "ignored when a study is passed — the study owns storage and "
-            "engine configuration"
-        )
+def run_cell_sweep(cell: str, out_dir: Path, *, study: Study):
+    """Run the cell's curated moves as one ``hillclimb`` session of
+    ``study``: the sweep lands in sessions.jsonl (report() rows, resumable
+    provenance) and shares the study-wide cache under the cell's namespace.
+    A caller that wants another evaluator (a stub for wiring checks) creates
+    the cell first with ``study.cell(arch, shape, evaluator=...)``."""
     arch_name, shape_name = cell.split(":")
-    arch = get_arch(arch_name)
-    shape = SHAPES[shape_name]
-    platform = "train" if shape.kind == "train" else "serve"
-    space = SPACES[platform]
-    # per-cell namespace in any shared cache (same discipline as Study.cell):
-    # the same knob dict on a different cell must never collide
-    platform_key = f"{platform}/{cell}"
-
-    if study is not None:
-        # a full Study session: the sweep lands in sessions.jsonl (report()
-        # rows, resumable provenance) and shares the study-wide cache under
-        # the cell's namespace — "hillclimb" is a registered strategy like
-        # any other
-        outcome = study.cell(arch_name, shape_name).optimize(
-            "hillclimb", moves=CANDIDATES[cell]
-        )
-        res = outcome.detail
-    else:
-        created = scheduler is None
-        if scheduler is None:
-            evaluator = RooflineEvaluator(
-                arch, shape, space, chips=256, memory_penalty="soft"
-            )
-            scheduler = TrialScheduler(
-                evaluator,
-                platform=platform_key,
-                cache_path=cache_path,
-                clear_caches_between_trials=True,
-            )
-        strategy = CuratedHillclimbStrategy(space, moves=CANDIDATES[cell])
-        try:
-            res = scheduler.run(strategy)
-        finally:
-            if created:
-                scheduler.close()
-
+    res = study.cell(arch_name, shape_name).optimize(
+        "hillclimb", moves=CANDIDATES[cell]
+    ).detail
     results = res.records
     base = results[0].get("t_step_s", float("nan")) if results else float("nan")
     for rec in results:
@@ -117,18 +80,13 @@ def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--cell", choices=list(CANDIDATES), required=True)
     ap.add_argument("--out", type=Path, default=Path("results/perf"))
-    ap.add_argument("--study", type=Path, default=None,
-                    help="Study directory (cache + trial log; replaces --cache)")
-    ap.add_argument("--cache", type=Path, default=None,
-                    help="legacy persistent JSONL evaluation cache "
-                         "(ignored when --study is given)")
+    ap.add_argument("--study", type=Path,
+                    default=Path("results/studies/hillclimb"),
+                    help="Study directory (cache + trial log + session "
+                         "provenance; created on first use)")
     args = ap.parse_args()
-    study = Study.open(args.study) if args.study else None
-    try:
-        run_cell_sweep(args.cell, args.out, cache_path=args.cache, study=study)
-    finally:
-        if study is not None:
-            study.close()
+    with Study.open(args.study) as study:
+        run_cell_sweep(args.cell, args.out, study=study)
     return 0
 
 

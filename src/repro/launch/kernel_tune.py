@@ -10,8 +10,10 @@ ship the incumbents into the tuned table the public entry points consult:
         --strategy tpe --budget 12 --study results/studies/kernels \
         --write-table
 
-``--transfer prior`` carries block-size evidence between shape classes of
-the same kernel (and never across kernels — :func:`kernel_similarity`).
+Without ``--study`` the sessions are stored in
+``results/studies/kernel_tune``. ``--transfer prior`` carries block-size
+evidence between shape classes of the same kernel (and never across
+kernels — :func:`kernel_similarity`).
 On a multi-chip host, fan trials out one-device-per-worker:
 
     PYTHONPATH=src python -m repro.launch.kernel_tune --kernel all \
@@ -41,7 +43,11 @@ from repro.core.kernel_tune import (
 )
 from repro.kernels import DEFAULT_TABLE_PATH
 from repro.launch.compile_cache import use_compile_cache
-from repro.launch.tune import add_engine_args, engine_config, open_study
+from repro.launch.tune import (
+    add_engine_args,
+    engine_overrides,
+    open_persistent_study,
+)
 
 
 def parse_shape(text: str) -> Tuple[int, ...]:
@@ -94,7 +100,7 @@ def main(argv=None):
                          "src/repro/kernels/tuned_table.json)")
     ap.add_argument("--out", type=Path, default=None,
                     help="write the per-cell summary JSON")
-    add_engine_args(ap)
+    add_engine_args(ap, Path("results/studies/kernel_tune"))
     args = ap.parse_args(argv)
     use_compile_cache()
 
@@ -120,8 +126,7 @@ def main(argv=None):
     mode = "interpret" if args.interpret else "compiled"
     summaries, table_updates = {}, {}
     fresh = memo = cached = 0
-    study = open_study(args, engine_config(args))
-    with study:
+    with open_persistent_study(args.study, engine_overrides(args)) as study:
         for kernel in kernels:
             shapes = args.shapes or DEFAULT_SHAPES[kernel]
             for shape in shapes:
@@ -146,7 +151,7 @@ def main(argv=None):
                     table_updates.update(tuned_entry(
                         kernel, args.dtype, evaluator.shape_class(),
                         outcome.best_config, outcome.best_time,
-                        source=f"study:{args.study or 'ephemeral'}"
+                        source=f"study:{args.study}"
                                f" algo={args.algorithm} seed={args.seed}"
                                f" mode={mode}",
                     ))

@@ -13,8 +13,8 @@ the cache left off.
         --cells llama3.2-1b:train_4k llama3.2-1b:decode_32k \
         --algorithm gsft --study results/studies/fleet
 
-Emits one summary JSON per cell plus a fleet table on stdout. The legacy
-``--cache``/``--log-dir`` pair still works when no ``--study`` is given.
+Emits one summary JSON per cell plus a fleet table on stdout. Without
+``--study`` the sessions are stored in ``results/studies/multicell``.
 """
 import os
 
@@ -26,7 +26,7 @@ from pathlib import Path
 
 from repro.configs.archs import get_arch
 from repro.configs.base import SHAPES
-from repro.core import SPACES, EngineConfig, Study
+from repro.core import SPACES, Study
 
 
 def cell_platform(shape_name: str) -> str:
@@ -36,30 +36,16 @@ def cell_platform(shape_name: str) -> str:
 def tune_cells(
     cells,
     *,
+    study: Study,
     algorithm: str = "gsft",
     chips: int = None,  # None = no opinion (256 on cell creation)
-    study: Study = None,
-    cache_path: Path = None,
-    log_dir: Path = None,
-    patience: int = None,
-    batch_size: int = None,
-    isolation: str = "inline",
-    jobs: int = 1,
-    trial_timeout: float = None,
-    prefilter: str = "off",
-    surrogate: str = "off",
     evaluator_factory=None,
     transfer: str = "off",
     **algo_kwargs,
 ):
-    """Tune each ``arch:shape`` cell; returns {cell: TuneOutcome}.
-
-    Pass ``study`` to make the matrix incremental across sessions (the CLI's
-    ``--study``); without one, a throwaway in-memory Study wraps the legacy
-    ``cache_path``/``log_dir`` files. Engine knobs and ``study`` are mutually
-    exclusive (configure the study's EngineConfig instead) — a conflicting
-    combination raises rather than silently ignoring the knobs, like
-    ``tune()``. ``evaluator_factory(arch, shape, space, platform)`` overrides
+    """Tune each ``arch:shape`` cell through ``study``; returns
+    {cell: TuneOutcome}. The study's EngineConfig configures every cell's
+    scheduler. ``evaluator_factory(arch, shape, space, platform)`` overrides
     the default RooflineEvaluator per cell (tests use a FunctionEvaluator
     matrix).
 
@@ -67,82 +53,45 @@ def tune_cells(
     histories from the shared cache (``Study.histories_for``): the matrix is
     walked in order, so cell N+1 transfers from cells 1..N (and from any cell
     a previous invocation left in the study)."""
-    owns_study = study is None
-    if owns_study:
-        study = Study(
-            engine=EngineConfig(
-                workers=jobs, isolation=isolation, timeout_s=trial_timeout,
-                patience=patience, batch_size=batch_size, prefilter=prefilter,
-                surrogate=surrogate,
-            ),
-            cache_path=cache_path,
-        )
-    else:
-        ignored = [
-            name for name, off_default in (
-                ("jobs", jobs != 1),
-                ("isolation", isolation != "inline"),
-                ("trial_timeout", trial_timeout is not None),
-                ("patience", patience is not None),
-                ("batch_size", batch_size is not None),
-                ("cache_path", cache_path is not None),
-                ("prefilter", prefilter != "off"),
-                ("surrogate", surrogate != "off"),
-            ) if off_default
-        ]
-        if ignored:
-            raise ValueError(
-                f"tune_cells(): {', '.join(ignored)} would be silently "
-                "ignored when an explicit study is passed — configure them "
-                "on the study's EngineConfig instead"
-            )
     outcomes = {}
-    try:
-        for cell in cells:
-            arch_name, sep, shape_name = cell.partition(":")
-            if not sep or not shape_name:
-                raise SystemExit(
-                    f"bad cell {cell!r}: expected ARCH:SHAPE, e.g. llama3.2-1b:train_4k"
-                )
-            if shape_name not in SHAPES:
-                raise SystemExit(
-                    f"bad cell {cell!r}: unknown shape {shape_name!r} "
-                    f"(known: {sorted(SHAPES)})"
-                )
-            arch = get_arch(arch_name)
-            if SHAPES[shape_name].name in arch.skip_shapes:
-                print(f"[{cell}] SKIP (arch skips shape)")
-                continue
-            platform = cell_platform(shape_name)
-            if study.has_cell(arch_name, shape_name):
-                # repeat pass over an open study (second algorithm, or a
-                # duplicated --cells entry): reuse the handle — and never
-                # build a second evaluator for the same cell. An explicit
-                # chips request still hits cell()'s conflict guard.
-                handle = study.cell(arch_name, shape_name, chips=chips)
-            else:
-                handle = study.cell(
-                    arch_name, shape_name, chips=chips,
-                    evaluator=(
-                        evaluator_factory(
-                            arch_name, shape_name, SPACES[platform], platform,
-                        ) if evaluator_factory else None
-                    ),
-                    log_path=(
-                        log_dir / f"{arch_name}__{shape_name}.jsonl"
-                        if log_dir else None
-                    ),
-                )
-            outcome = handle.optimize(algorithm, transfer=transfer, **algo_kwargs)
-            outcomes[cell] = outcome
-            s = outcome.summary()
-            print(f"[{cell}] best={s['best_time_s']:.4f}s "
-                  f"default={s['default_time_s']:.4f}s "
-                  f"reduction={s['reduction_pct']:.1f}% "
-                  f"evals={s['evaluations']} cache={s.get('cache_stats')}", flush=True)
-    finally:
-        if owns_study:
-            study.close()
+    for cell in cells:
+        arch_name, sep, shape_name = cell.partition(":")
+        if not sep or not shape_name:
+            raise SystemExit(
+                f"bad cell {cell!r}: expected ARCH:SHAPE, e.g. llama3.2-1b:train_4k"
+            )
+        if shape_name not in SHAPES:
+            raise SystemExit(
+                f"bad cell {cell!r}: unknown shape {shape_name!r} "
+                f"(known: {sorted(SHAPES)})"
+            )
+        arch = get_arch(arch_name)
+        if SHAPES[shape_name].name in arch.skip_shapes:
+            print(f"[{cell}] SKIP (arch skips shape)")
+            continue
+        platform = cell_platform(shape_name)
+        if study.has_cell(arch_name, shape_name):
+            # repeat pass over an open study (second algorithm, or a
+            # duplicated --cells entry): reuse the handle — and never
+            # build a second evaluator for the same cell. An explicit
+            # chips request still hits cell()'s conflict guard.
+            handle = study.cell(arch_name, shape_name, chips=chips)
+        else:
+            handle = study.cell(
+                arch_name, shape_name, chips=chips,
+                evaluator=(
+                    evaluator_factory(
+                        arch_name, shape_name, SPACES[platform], platform,
+                    ) if evaluator_factory else None
+                ),
+            )
+        outcome = handle.optimize(algorithm, transfer=transfer, **algo_kwargs)
+        outcomes[cell] = outcome
+        s = outcome.summary()
+        print(f"[{cell}] best={s['best_time_s']:.4f}s "
+              f"default={s['default_time_s']:.4f}s "
+              f"reduction={s['reduction_pct']:.1f}% "
+              f"evals={s['evaluations']} cache={s.get('cache_stats')}", flush=True)
     return outcomes
 
 
@@ -181,13 +130,10 @@ def main(argv=None):
                          "fn(arch, shape, space, platform) overriding the "
                          "default RooflineEvaluator per cell (tests/CI use a "
                          "deterministic synthetic matrix)")
-    ap.add_argument("--study", type=Path, default=None,
+    ap.add_argument("--study", type=Path,
+                    default=Path("results/studies/multicell"),
                     help="Study directory shared by every cell (cache + log + "
-                         "session provenance; replaces --cache/--log-dir)")
-    ap.add_argument("--cache", type=Path, default=Path("results/eval_cache.jsonl"),
-                    help="legacy shared cache (ignored when --study is given)")
-    ap.add_argument("--log-dir", type=Path, default=Path("results/multicell"),
-                    help="legacy per-cell logs (ignored when --study is given)")
+                         "session provenance; created on first use)")
     ap.add_argument("--out", type=Path, default=Path("results/multicell/summary.json"))
     # None defaults = "flag not given" so explicit values can override a
     # persistent study's stored engine without untyped flags clobbering it
@@ -222,30 +168,8 @@ def main(argv=None):
         }
     else:  # tpe — each cell warm-starts from its own slice of the shared cache
         algo_kwargs = {"budget": args.budget, "seed": args.seed}
-    from repro.launch.tune import engine_config, engine_overrides, \
-        open_persistent_study
+    from repro.launch.tune import engine_overrides, open_persistent_study
 
-    # explicitly-typed flags overlay the stored engine per-field; untyped
-    # flags don't clobber what the study was configured with
-    study = open_persistent_study(args.study, engine_overrides(args)) \
-        if args.study else None
-    # with --study the engine flags configure the Study's EngineConfig above;
-    # without one they flow into tune_cells' throwaway in-memory study
-    if study is not None:
-        engine_kwargs = {}
-    else:
-        engine = engine_config(args)  # fills engine defaults for untyped flags
-        engine_kwargs = dict(
-            cache_path=args.cache,
-            log_dir=args.log_dir,
-            patience=engine.patience,
-            batch_size=engine.batch_size,
-            isolation=engine.isolation,
-            jobs=engine.workers,
-            trial_timeout=engine.timeout_s,
-            prefilter=engine.prefilter,
-            surrogate=engine.surrogate,
-        )
     evaluator_factory = None
     if args.evaluator_factory:
         import importlib
@@ -257,20 +181,18 @@ def main(argv=None):
                 "expected PKG.MOD:FN"
             )
         evaluator_factory = getattr(importlib.import_module(mod), attr)
-    try:
+    # explicitly-typed flags overlay the stored engine per-field; untyped
+    # flags don't clobber what the study was configured with
+    with open_persistent_study(args.study, engine_overrides(args)) as study:
         outcomes = tune_cells(
             args.cells,
+            study=study,
             algorithm=args.algorithm,
             chips=args.chips,
-            study=study,
             transfer=args.transfer,
             evaluator_factory=evaluator_factory,
-            **engine_kwargs,
             **algo_kwargs,
         )
-    finally:
-        if study is not None:
-            study.close()
     args.out.parent.mkdir(parents=True, exist_ok=True)
     args.out.write_text(json.dumps(
         {cell: o.summary() for cell, o in outcomes.items()}, indent=1, default=str

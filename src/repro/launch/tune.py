@@ -22,8 +22,7 @@ batched acquisition) warm-starts its observation history from the same study:
     PYTHONPATH=src python -m repro.launch.tune --platform wordcount \
         --strategy tpe --budget 48 --jobs 4 --study results/studies/wc
 
-The legacy ``--cache``/``--log`` pair still works for ad-hoc runs without a
-study directory.
+Without ``--study`` the session is stored in ``results/studies/tune``.
 """
 import os
 
@@ -40,13 +39,14 @@ from repro.core import SPACES, EngineConfig, Study
 from repro.core.evaluators import RooflineEvaluator
 
 
-def add_engine_args(ap: argparse.ArgumentParser):
-    """Engine knobs shared by every driver that runs the TrialScheduler.
-    They populate one validated EngineConfig (see ``engine_config``)."""
-    ap.add_argument("--study", type=Path, default=None,
+def add_engine_args(ap: argparse.ArgumentParser, default_study: Path):
+    """The study directory and the engine knobs shared by every driver that
+    runs the TrialScheduler. The knobs overlay the study's stored
+    EngineConfig (see ``open_persistent_study``)."""
+    ap.add_argument("--study", type=Path, default=default_study,
                     help="Study directory owning cache + log + session "
-                         "provenance (created on first use; replaces the "
-                         "ad-hoc --cache/--log pair)")
+                         f"provenance (created on first use; default "
+                         f"{default_study})")
     # engine flags default to None (= "not given") so an explicitly-typed
     # value — even one equal to the engine default, like --jobs 1 — is
     # distinguishable and can override a persistent study's stored engine
@@ -54,9 +54,6 @@ def add_engine_args(ap: argparse.ArgumentParser):
                     help="parallel trials per batch (thread pool; default 1)")
     ap.add_argument("--batch", type=int, default=None,
                     help="max configs per ask() batch (default: whole phase)")
-    ap.add_argument("--cache", type=Path, default=None,
-                    help="persistent JSONL evaluation cache shared across "
-                         "runs (ignored when --study is given)")
     ap.add_argument("--patience", type=int, default=None,
                     help="stop when best hasn't improved in N batches")
     ap.add_argument("--trial-timeout", "--timeout", dest="trial_timeout",
@@ -118,12 +115,6 @@ def engine_overrides(args) -> dict:
     }
 
 
-def engine_config(args) -> EngineConfig:
-    """One validated EngineConfig from the CLI engine flags (engine defaults
-    fill anything the user didn't type)."""
-    return EngineConfig(**engine_overrides(args))
-
-
 def open_persistent_study(path: Path, overrides: dict) -> Study:
     """Open (or create) the study at ``path``, overlaying exactly the engine
     flags the CLI user typed onto the study's stored engine — an untyped
@@ -136,15 +127,6 @@ def open_persistent_study(path: Path, overrides: dict) -> Study:
             study.engine = study.engine.replace(**overrides)
         return study
     return Study.create(path, engine=EngineConfig(**overrides))
-
-
-def open_study(args, engine: EngineConfig) -> Study:
-    """``--study DIR`` opens (or creates) a persistent Study; without it an
-    in-memory Study wraps the legacy --cache/--log files."""
-    if args.study:
-        return open_persistent_study(args.study, engine_overrides(args))
-    return Study(engine=engine, cache_path=args.cache,
-                 log_path=getattr(args, "log", None))
 
 
 def main(argv=None):
@@ -183,10 +165,8 @@ def main(argv=None):
                          "incumbents (gsft/crs/tpe), prior = distance-decayed "
                          "Parzen prior over sibling observations (tpe); "
                          "sibling trials never count toward --budget")
-    ap.add_argument("--log", type=Path, default=Path("results/tune_log.jsonl"),
-                    help="trial log (ignored when --study is given)")
     ap.add_argument("--out", type=Path, default=None, help="write best config JSON")
-    add_engine_args(ap)
+    add_engine_args(ap, Path("results/studies/tune"))
     args = ap.parse_args(argv)
 
     if args.platform == "wordcount":
@@ -230,7 +210,7 @@ def main(argv=None):
                       seed=args.seed)
     # the real platform name namespaces the persistent cache — wordcount
     # records must never alias the roofline "train" platform's
-    study = open_study(args, engine_config(args))
+    study = open_persistent_study(args.study, engine_overrides(args))
     with study:
         outcome = study.optimize(
             platform_key,

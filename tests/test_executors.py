@@ -1,8 +1,8 @@
 """Subprocess execution backend (hard per-trial isolation) + the
 scheduler-correctness sweep that rode along with it: SIGKILLed hung trials,
 crash containment, warm worker reuse, spec serialization, per-future batch
-deadlines, over-deadline measurement persistence, robust log readers,
-per-run accounting, and the tune()/scheduler conflict guard.
+deadlines, over-deadline measurement persistence, robust log readers, and
+per-run accounting.
 
 Worker-side functions must be module-level: the spawn start method ships
 them to workers by pickle-by-reference.
@@ -13,7 +13,7 @@ import time
 
 import pytest
 
-from repro.core import TrialScheduler, tune
+from repro.core import EngineConfig, Study, TrialScheduler, run_session
 from repro.core.evaluators import FunctionEvaluator
 from repro.core.executors import EvaluatorSpec, SubprocessBackend, make_backend
 from repro.core.scheduler import best_from_log, read_log
@@ -166,14 +166,16 @@ def test_make_backend_registry():
 
 
 def test_subprocess_tune_end_to_end(tmp_path):
-    """Full tune() through the subprocess backend: same optimum as inline."""
-    out = tune(
+    """A full Study session through the subprocess backend: same optimum
+    as inline."""
+    out = Study(
+        engine=EngineConfig(isolation="subprocess", workers=2),
+        log_path=tmp_path / "log.jsonl",
+    ).optimize(
         "train", "gsft", FunctionEvaluator(_mesh_objective),
         active_params=["mesh_model_parallel"], samples_per_param=3,
-        isolation="subprocess", max_workers=2,
-        log_path=tmp_path / "log.jsonl",
     )
-    ref = tune(
+    ref = Study().optimize(
         "train", "gsft", FunctionEvaluator(_mesh_objective),
         active_params=["mesh_model_parallel"], samples_per_param=3,
     )
@@ -359,28 +361,9 @@ def test_shared_scheduler_reports_per_run_deltas():
 
 def test_shared_scheduler_tune_outcome_not_inflated():
     sched = TrialScheduler(FunctionEvaluator(_mesh_objective))
-    out1 = tune("train", "gsft", sched.evaluator, scheduler=sched,
-                active_params=["mesh_model_parallel"], samples_per_param=3)
-    out2 = tune("train", "gsft", sched.evaluator, scheduler=sched,
-                active_params=["microbatch_size"], samples_per_param=3)
+    out1 = run_session(sched, "train", "gsft", TRAIN_SPACE,
+                       active_params=["mesh_model_parallel"],
+                       samples_per_param=3)
+    out2 = run_session(sched, "train", "gsft", TRAIN_SPACE,
+                       active_params=["microbatch_size"], samples_per_param=3)
     assert out1.evaluations + out2.evaluations == sched.num_evaluations
-
-
-# ------------------------------- satellite: tune() vs scheduler conflict
-
-
-def test_tune_rejects_engine_kwargs_with_explicit_scheduler():
-    sched = TrialScheduler(FunctionEvaluator(_mesh_objective))
-    with pytest.raises(ValueError, match="max_workers.*ignored"):
-        tune("train", "gsft", sched.evaluator, scheduler=sched,
-             max_workers=4, active_params=["mesh_model_parallel"])
-    with pytest.raises(ValueError, match="timeout_s, retries"):
-        tune("train", "gsft", sched.evaluator, scheduler=sched,
-             timeout_s=1.0, retries=2, active_params=["mesh_model_parallel"])
-    with pytest.raises(ValueError, match="isolation"):
-        tune("train", "gsft", sched.evaluator, scheduler=sched,
-             isolation="subprocess", active_params=["mesh_model_parallel"])
-    with pytest.raises(ValueError, match="log_path"):
-        tune("train", "gsft", sched.evaluator, scheduler=sched,
-             log_path=__import__("pathlib").Path("x.jsonl"),
-             active_params=["mesh_model_parallel"])

@@ -114,22 +114,12 @@ def test_multicell_duplicate_cells_in_one_invocation(tmp_path):
     assert set(out) == {CELLS[0]}  # second entry replays the same sessions
 
 
-def test_multicell_rejects_engine_kwargs_with_explicit_study(tmp_path):
-    """Engine knobs alongside an explicit study must raise (they would be
-    silently ignored) — the same guard tune() has for explicit schedulers."""
-    with Study.open(tmp_path / "study") as study:
-        with pytest.raises(ValueError, match="jobs.*ignored"):
-            tune_cells(CELLS, study=study, jobs=8)
-        with pytest.raises(ValueError, match="isolation, trial_timeout"):
-            tune_cells(CELLS, study=study, isolation="subprocess",
-                       trial_timeout=120.0)
-
-
 def test_multicell_rejects_malformed_cells(tmp_path):
-    with pytest.raises(SystemExit, match="expected ARCH:SHAPE"):
-        tune_cells(["llama3.2-1b"], cache_path=tmp_path / "c.jsonl")
-    with pytest.raises(SystemExit, match="unknown shape"):
-        tune_cells(["llama3.2-1b:bogus_shape"], cache_path=tmp_path / "c.jsonl")
+    with Study.create(tmp_path / "study") as study:
+        with pytest.raises(SystemExit, match="expected ARCH:SHAPE"):
+            tune_cells(["llama3.2-1b"], study=study)
+        with pytest.raises(SystemExit, match="unknown shape"):
+            tune_cells(["llama3.2-1b:bogus_shape"], study=study)
 
 
 def test_cell_platform_maps_shape_kind():

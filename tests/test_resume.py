@@ -14,7 +14,7 @@ import time
 
 import pytest
 
-from repro.core import TRAIN_SPACE, TrialScheduler, tune
+from repro.core import TRAIN_SPACE, EngineConfig, Study, TrialScheduler
 from repro.core.evaluators import FunctionEvaluator
 from repro.core.scheduler import read_log
 from repro.core.strategies import CRSStrategy, GridFinerStrategy, TPEStrategy
@@ -135,17 +135,20 @@ def test_tpe_crash_resume_warm_history_pays_only_remaining_budget(tmp_path):
         sched1.run(TPEStrategy(TRAIN_SPACE, max_trials=budget, seed=3), batch_size=4)
     assert len(cache.read_text().splitlines()) == killed
 
-    # resume through tune(): the cache warm-starts the observation history
+    # resume through a Study session: the cache warm-starts the observation
+    # history
     ev2 = Counting()
-    out2 = tune("train", "tpe", ev2, cache_path=cache, max_trials=budget, seed=3)
+    out2 = Study(cache_path=cache).optimize(
+        "train", "tpe", ev2, max_trials=budget, seed=3)
     assert out2.detail.warm_started == killed
-    # fresh = remaining budget + the defaults trial tune() always measures
+    # fresh = remaining budget + the defaults trial a session always measures
     assert ev2.calls <= budget - killed + 1
     assert out2.detail.n_observations >= budget
 
     # complete cache: nothing fresh, incumbent identical
     ev3 = Counting()
-    out3 = tune("train", "tpe", ev3, cache_path=cache, max_trials=budget, seed=3)
+    out3 = Study(cache_path=cache).optimize(
+        "train", "tpe", ev3, max_trials=budget, seed=3)
     assert ev3.calls == 0
     assert out3.cache_stats["fresh"] == 0
     assert out3.best_config == out2.best_config
@@ -226,10 +229,9 @@ def test_timeouts_surfaced_in_tune_summary():
             time.sleep(0.2)
         return float(cfg["mesh_model_parallel"])
 
-    out = tune(
+    out = Study(engine=EngineConfig(timeout_s=0.1)).optimize(
         "train", "gsft", FunctionEvaluator(sometimes_slow),
         active_params=["mesh_model_parallel"], samples_per_param=7,
-        timeout_s=0.1,
     )
     assert out.timeouts > 0
     assert out.summary()["timeouts"] == out.timeouts

@@ -1,7 +1,7 @@
 """The ask/tell Strategy + TrialScheduler engine: cache accounting,
 parallel-vs-serial equivalence, early stopping, persistent warm-cache
 re-runs (zero fresh evaluations), the >=2x parallel wall-clock demo, and
-ask/tell parity of the ported GSFT/CRS against their legacy wrappers."""
+serial-vs-parallel parity of GSFT/CRS."""
 import json
 import threading
 import time
@@ -9,13 +9,10 @@ import time
 import pytest
 
 from repro.core import (
-    CMPE,
     TRAIN_SPACE,
+    Study,
     TrialScheduler,
-    controlled_random_search,
-    grid_search_finer_tuning,
     make_strategy,
-    tune,
 )
 from repro.core.evaluators import FunctionEvaluator
 from repro.core.scheduler import read_log
@@ -79,13 +76,13 @@ def test_warm_cache_rerun_performs_zero_fresh_evaluations(tmp_path):
     """Acceptance: a warm-cache re-run costs nothing fresh."""
     cache = tmp_path / "cache.jsonl"
     cold_ev = CountingEvaluator()
-    cold = tune("train", "gsft", cold_ev, cache_path=cache,
-                active_params=ACTIVE, samples_per_param=3)
+    cold = Study(cache_path=cache).optimize(
+        "train", "gsft", cold_ev, active_params=ACTIVE, samples_per_param=3)
     assert cold_ev.calls > 0
 
     warm_ev = CountingEvaluator()
-    warm = tune("train", "gsft", warm_ev, cache_path=cache,
-                active_params=ACTIVE, samples_per_param=3)
+    warm = Study(cache_path=cache).optimize(
+        "train", "gsft", warm_ev, active_params=ACTIVE, samples_per_param=3)
     assert warm_ev.calls == 0  # every trial replayed from the JSONL cache
     assert warm.best_config == cold.best_config
     assert warm.best_time == cold.best_time
@@ -251,15 +248,17 @@ def test_parallel_timeout_returns_promptly_with_hung_worker():
     assert all("TrialTimeout" in t.error for t in trials)
 
 
-# --------------------------------------------- ask/tell parity vs legacy path
+# ------------------------------------------ ask/tell parity, serial vs parallel
 
 
 def test_gsft_askfell_parity_with_legacy_wrapper(tmp_path):
-    """The strategy driven in parallel batches must reproduce the legacy
-    serial wrapper exactly on a synthetic objective with a known optimum."""
-    legacy = CMPE(FunctionEvaluator(quad_objective), log_path=tmp_path / "l.jsonl")
-    res_legacy = grid_search_finer_tuning(
-        TRAIN_SPACE, legacy, active_params=ACTIVE, samples_per_param=4
+    """The strategy driven in parallel batches must reproduce the serial
+    path (one trial at a time in ask order, what the paper's CMPE loop ran)
+    exactly on a synthetic objective with a known optimum."""
+    serial = TrialScheduler(FunctionEvaluator(quad_objective), max_workers=1,
+                            log_path=tmp_path / "l.jsonl")
+    res_serial = serial.run(
+        GridFinerStrategy(TRAIN_SPACE, active_params=ACTIVE, samples_per_param=4)
     )
 
     engine = TrialScheduler(FunctionEvaluator(quad_objective), max_workers=4)
@@ -267,27 +266,29 @@ def test_gsft_askfell_parity_with_legacy_wrapper(tmp_path):
         GridFinerStrategy(TRAIN_SPACE, active_params=ACTIVE, samples_per_param=4),
         batch_size=16,
     )
-    assert res_legacy.best_config == res_engine.best_config
-    assert res_legacy.best_time == res_engine.best_time
-    assert res_legacy.grid_sizes == res_engine.grid_sizes
+    assert res_serial.best_config == res_engine.best_config
+    assert res_serial.best_time == res_engine.best_time
+    assert res_serial.grid_sizes == res_engine.grid_sizes
     assert res_engine.best_config["mesh_model_parallel"] == 8  # known optimum
     assert res_engine.best_config["remat_policy"] == "dots"
 
 
 def test_crs_askfell_parity_with_legacy_wrapper():
-    legacy = CMPE(FunctionEvaluator(quad_objective))
-    res_legacy = controlled_random_search(
-        TRAIN_SPACE, legacy, m=12, k=4, max_rounds=4, seed=7
+    """Serial and parallel-batched CRS draw the same rounds: a round's draws
+    are made before any result is consumed."""
+    serial = TrialScheduler(FunctionEvaluator(quad_objective), max_workers=1)
+    res_serial = serial.run(
+        CRSStrategy(TRAIN_SPACE, m=12, k=4, max_rounds=4, seed=7)
     )
 
     engine = TrialScheduler(FunctionEvaluator(quad_objective), max_workers=4)
     res_engine = engine.run(
         CRSStrategy(TRAIN_SPACE, m=12, k=4, max_rounds=4, seed=7), batch_size=6
     )
-    assert res_legacy.best_config == res_engine.best_config
-    assert res_legacy.best_time == res_engine.best_time
-    assert res_legacy.rounds == res_engine.rounds
-    assert res_legacy.bound_history == res_engine.bound_history
+    assert res_serial.best_config == res_engine.best_config
+    assert res_serial.best_time == res_engine.best_time
+    assert res_serial.rounds == res_engine.rounds
+    assert res_serial.bound_history == res_engine.bound_history
 
 
 # ------------------------------------------------------------ hillclimb port
@@ -349,7 +350,7 @@ def test_make_strategy_registry():
 
 
 def test_tune_supports_hillclimb_algorithm():
-    out = tune(
+    out = Study().optimize(
         "train", "hillclimb", FunctionEvaluator(quad_objective),
         moves=[("baseline", "defaults", {}),
                ("mp8", "smaller collectives", {"mesh_model_parallel": 8})],

@@ -12,7 +12,6 @@ from repro.core import (
     TRAIN_SPACE,
     EngineConfig,
     Study,
-    tune,
 )
 from repro.core.evaluators import FunctionEvaluator
 from repro.core.executors import EvaluatorSpec
@@ -297,22 +296,53 @@ def test_cli_open_study_honors_stored_engine(tmp_path):
     overlays ONLY its own field, never resetting the other stored knobs."""
     from argparse import Namespace
 
-    from repro.launch.tune import engine_config, open_study
+    from repro.launch.tune import engine_overrides, open_persistent_study
+
+    def open_study(args):
+        return open_persistent_study(args.study, engine_overrides(args))
 
     stored = EngineConfig(workers=4, timeout_s=120.0)
     Study.create(tmp_path / "s", engine=stored)
     untyped = Namespace(study=tmp_path / "s", jobs=None, isolation=None,
                         trial_timeout=None, retries=None, patience=None,
-                        batch=None, cache=None, log=None)
-    assert open_study(untyped, engine_config(untyped)).engine == stored
+                        batch=None)
+    assert open_study(untyped).engine == stored
     # ...an explicit flag wins for its field but doesn't clobber the rest
     explicit = Namespace(**{**vars(untyped), "jobs": 8})
-    merged = open_study(explicit, engine_config(explicit)).engine
+    merged = open_study(explicit).engine
     assert merged.workers == 8
     assert merged.timeout_s == 120.0  # stored knob survives the override
     # an explicitly-typed default value is a real override too (--jobs 1)
     reset = Namespace(**{**vars(untyped), "jobs": 1})
-    assert open_study(reset, engine_config(reset)).engine.workers == 1
+    assert open_study(reset).engine.workers == 1
+
+
+@pytest.mark.parametrize("algorithm_args", [
+    ["--algorithm", "gsft", "--samples", "2", "--active", "replication"],
+    ["--algorithm", "crs", "--m", "4", "--k", "2", "--rounds", "1"],
+    ["--algorithm", "tpe", "--budget", "4", "--startup", "2",
+     "--round-size", "2"],
+], ids=["gsft", "crs", "tpe"])
+def test_cli_tune_study_round_trip(tmp_path, capsys, algorithm_args):
+    """The tuning CLI stores its session in the --study directory, and a
+    warm re-run into that directory replays it: nothing fresh, the same
+    incumbent."""
+    from repro.launch.tune import main
+
+    study_dir = tmp_path / "study"
+    argv = ["--platform", "wordcount", *algorithm_args, "--jobs", "4",
+            "--isolation", "inline", "--study", str(study_dir)]
+    assert main(argv) == 0
+    cold = json.loads(capsys.readouterr().out)
+    assert cold["cache_stats"]["fresh"] > 0
+    for name in ("study.json", "cache.jsonl", "trials.jsonl", "sessions.jsonl"):
+        assert (study_dir / name).exists(), name
+
+    assert main(argv) == 0
+    warm = json.loads(capsys.readouterr().out)
+    assert warm["cache_stats"]["fresh"] == 0
+    assert warm["best_config"] == cold["best_config"]
+    assert warm["best_time_s"] == cold["best_time_s"]
 
 
 def test_resume_replays_an_explicit_history(tmp_path):
@@ -416,8 +446,8 @@ def test_read_log_missing_path_raises():
 
 
 def test_optimize_rejects_engine_kwargs_with_clear_error(tmp_path):
-    """Engine knobs passed as strategy kwargs (the old tune() surface) get a
-    ValueError pointing at EngineConfig, not a confusing TypeError."""
+    """Engine knobs passed as strategy kwargs get a ValueError pointing at
+    EngineConfig, not a confusing TypeError."""
     study = Study.create(tmp_path / "s")
     with pytest.raises(ValueError, match="batch_size.*EngineConfig"):
         study.optimize("train", "gsft", FunctionEvaluator(quad_objective),
@@ -596,18 +626,3 @@ def test_cells_namespace_the_shared_cache(tmp_path):
                       samples_per_param=2)
     assert a.best_time == 5.0 and b.best_time == 1.0
     study.close()
-
-
-# ------------------------------------------------------------- tune shim
-
-
-def test_tune_shim_matches_study_optimize(tmp_path):
-    with pytest.warns(DeprecationWarning, match="tune\\(\\) is deprecated"):
-        shim = tune("train", "gsft", FunctionEvaluator(quad_objective),
-                    cache_path=tmp_path / "shim.jsonl", **GSFT_KW)
-    study_out = Study.create(tmp_path / "s").optimize(
-        "train", "gsft", FunctionEvaluator(quad_objective), **GSFT_KW)
-    assert shim.best_config == study_out.best_config
-    assert shim.best_time == study_out.best_time
-    assert shim.evaluations == study_out.evaluations
-    assert shim.cache_stats == study_out.cache_stats

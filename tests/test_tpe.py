@@ -6,7 +6,7 @@ import time
 
 import pytest
 
-from repro.core import TRAIN_SPACE, TrialScheduler, make_strategy, tune
+from repro.core import TRAIN_SPACE, Study, TrialScheduler, make_strategy
 from repro.core.evaluators import FunctionEvaluator
 from repro.core.scheduler import Trial, config_key
 from repro.core.strategies import CRSStrategy, TPEStrategy
@@ -50,8 +50,8 @@ def test_tpe_registered_in_strategy_registry():
 
 
 def test_tune_supports_tpe_algorithm():
-    out = tune("train", "tpe", FunctionEvaluator(quad_objective),
-               max_trials=40, seed=1)
+    out = Study().optimize("train", "tpe", FunctionEvaluator(quad_objective),
+                           max_trials=40, seed=1)
     assert isinstance(out.detail, TPEResult)
     assert out.evaluations >= 1
     assert out.best_time < out.default_time  # beat the all-defaults config
@@ -66,10 +66,11 @@ def test_tpe_beats_pure_random_at_equal_budget():
     startup distribution — same budget, same seed family, pure random via a
     single uncontracted CRS round."""
     budget = 48
-    tpe = tune("train", "tpe", FunctionEvaluator(quad_objective),
-               max_trials=budget, seed=1)
-    rand = tune("train", "crs", FunctionEvaluator(quad_objective),
-                m=budget, k=4, max_rounds=1, seed=1)
+    study = Study()
+    tpe = study.optimize("train", "tpe", FunctionEvaluator(quad_objective),
+                         max_trials=budget, seed=1)
+    rand = study.optimize("train", "crs", FunctionEvaluator(quad_objective),
+                          m=budget, k=4, max_rounds=1, seed=1)
     assert tpe.best_time <= rand.best_time
     assert tpe.best_config["mesh_model_parallel"] == 8  # found the optimum knob
 
@@ -227,15 +228,15 @@ def test_tpe_foreign_strategy_history_is_free_evidence_not_budget():
 
 
 def test_tpe_budget_survives_shared_cache_with_other_strategy(tmp_path):
-    """The documented shared-cache workflow: gsft first, then tpe with the
-    same --cache. TPE must still run its own fresh trials."""
-    cache = tmp_path / "cache.jsonl"
-    tune("train", "gsft", FunctionEvaluator(quad_objective), cache_path=cache,
-         active_params=["mesh_model_parallel", "remat_policy"],
-         samples_per_param=3)
+    """The documented shared-cache workflow: gsft first, then tpe in the
+    same study. TPE must still run its own fresh trials."""
+    study = Study.create(tmp_path / "study")
+    study.optimize("train", "gsft", FunctionEvaluator(quad_objective),
+                   active_params=["mesh_model_parallel", "remat_policy"],
+                   samples_per_param=3)
 
     ev = CountingEvaluator()
-    out = tune("train", "tpe", ev, cache_path=cache, max_trials=12, seed=0)
+    out = study.optimize("train", "tpe", ev, max_trials=12, seed=0)
     assert ev.calls > 0  # budget was NOT pre-consumed by gsft's records
     assert out.detail.warm_started > 0  # but their evidence was used
     assert out.detail.n_observations >= out.detail.warm_started + 12

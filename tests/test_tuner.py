@@ -1,5 +1,6 @@
-"""Tuner invariants: the paper's two algorithms + CMPE, on synthetic
-objectives with known optima (property-based where it pays)."""
+"""Tuner invariants: the paper's two algorithms (through ``Study.optimize``)
+and the TrialScheduler's CMPE duties (memoise, log, contain failures), on
+synthetic objectives with known optima (property-based where it pays)."""
 import json
 import math
 from pathlib import Path
@@ -7,8 +8,7 @@ from pathlib import Path
 import pytest
 from _hyp import given, settings, st
 
-from repro.core import (CMPE, SPACES, best_from_log, controlled_random_search,
-                        grid_search_finer_tuning, read_log, tune)
+from repro.core import SPACES, Study, TrialScheduler, best_from_log, read_log
 from repro.core.evaluators import FunctionEvaluator
 from repro.core.space import TRAIN_SPACE
 
@@ -22,9 +22,8 @@ def quad_objective(cfg):
 
 
 def test_gsft_finds_known_optimum(tmp_path):
-    out = tune(
+    out = Study(log_path=tmp_path / "log.jsonl").optimize(
         "train", "gsft", FunctionEvaluator(quad_objective),
-        log_path=tmp_path / "log.jsonl",
         active_params=["mesh_model_parallel", "microbatch_size", "remat_policy"],
         samples_per_param=4,
     )
@@ -36,18 +35,18 @@ def test_gsft_finds_known_optimum(tmp_path):
 
 def test_gsft_finer_pass_improves_or_holds(tmp_path):
     """Phase 2 (finer tuning) may never return something worse than phase 1."""
-    cmpe = CMPE(FunctionEvaluator(quad_objective), log_path=tmp_path / "l.jsonl")
-    res = grid_search_finer_tuning(
-        TRAIN_SPACE, cmpe,
+    out = Study(log_path=tmp_path / "l.jsonl").optimize(
+        "train", "gsft", FunctionEvaluator(quad_objective),
         active_params=["mesh_model_parallel", "microbatch_size"],
         samples_per_param=3,
     )
+    res = out.detail
     assert res.best_time <= res.phase1_time
 
 
 def test_crs_bounds_contract_and_improve():
-    cmpe = CMPE(FunctionEvaluator(quad_objective))
-    res = controlled_random_search(TRAIN_SPACE, cmpe, m=16, k=4, max_rounds=4, seed=1)
+    res = Study().optimize("train", "crs", FunctionEvaluator(quad_objective),
+                           m=16, k=4, max_rounds=4, seed=1).detail
     # bounds must contract monotonically per numeric parameter
     for name in ("mesh_model_parallel", "microbatch_size"):
         widths = [hi - lo for (lo, hi) in (b[name] for b in res.bound_history)]
@@ -58,11 +57,13 @@ def test_crs_bounds_contract_and_improve():
 
 def test_gsft_beats_or_matches_crs_same_objective():
     """The paper's comparison (§XI): GSFT found better configs than CRS."""
-    g = tune("train", "gsft", FunctionEvaluator(quad_objective),
-             active_params=["mesh_model_parallel", "microbatch_size", "remat_policy"],
-             samples_per_param=4)
-    c = tune("train", "crs", FunctionEvaluator(quad_objective), m=12, k=4,
-             max_rounds=4, seed=0)
+    study = Study()
+    g = study.optimize("train", "gsft", FunctionEvaluator(quad_objective),
+                       active_params=["mesh_model_parallel", "microbatch_size",
+                                      "remat_policy"],
+                       samples_per_param=4)
+    c = study.optimize("train", "crs", FunctionEvaluator(quad_objective),
+                       m=12, k=4, max_rounds=4, seed=0)
     assert g.best_time <= c.best_time + 1e-9
 
 
@@ -73,10 +74,10 @@ def test_cmpe_logs_and_memoizes(tmp_path):
         calls.append(1)
         return 1.0
 
-    cmpe = CMPE(FunctionEvaluator(f), log_path=tmp_path / "log.jsonl")
+    sched = TrialScheduler(FunctionEvaluator(f), log_path=tmp_path / "log.jsonl")
     cfg = TRAIN_SPACE.defaults()
-    cmpe.evaluate(cfg)
-    cmpe.evaluate(cfg)  # memoized — evaluator runs once
+    sched.evaluate(cfg)
+    sched.evaluate(cfg)  # memoized — evaluator runs once
     assert len(calls) == 1
     recs = read_log(tmp_path / "log.jsonl")
     assert len(recs) == 2 and recs[1]["cached"]
@@ -87,8 +88,8 @@ def test_cmpe_failed_trial_is_logged_not_raised(tmp_path):
     def f(cfg):
         raise RuntimeError("injected OOM")
 
-    cmpe = CMPE(FunctionEvaluator(f), log_path=tmp_path / "log.jsonl")
-    t = cmpe.evaluate(TRAIN_SPACE.defaults())
+    sched = TrialScheduler(FunctionEvaluator(f), log_path=tmp_path / "log.jsonl")
+    t = sched.evaluate(TRAIN_SPACE.defaults())
     assert t == float("inf")
     assert read_log(tmp_path / "log.jsonl")[0]["error"]
 
@@ -99,7 +100,8 @@ def test_tuner_never_returns_worse_than_default():
     def hostile(cfg):
         return 1.0 if cfg == TRAIN_SPACE.defaults() else 5.0
 
-    out = tune("train", "crs", FunctionEvaluator(hostile), m=6, k=2, max_rounds=2)
+    out = Study().optimize("train", "crs", FunctionEvaluator(hostile),
+                           m=6, k=2, max_rounds=2)
     assert out.best_time == 1.0
     assert out.best_config == TRAIN_SPACE.defaults()
 

@@ -333,6 +333,13 @@ def _attn_scoped(p, h, ctx: Ctx, *, window, cache, prefix, cross, causal):
         bias = p.get(prefix + "b" + name[-1])
         if bias is not None:
             y = y + bias.astype(cd)
+        if ctx.mode == "decode":
+            # Decode's dot reads its weight in place only if what follows
+            # cannot choose its output layout: RoPE's half-split of each
+            # head otherwise makes the TPU compiler slice the layer's weight
+            # out of the stack and copy it transposed, every step (wq: 72 MiB
+            # a layer at d 6144).
+            y = jax.lax.optimization_barrier(y)
         return y.reshape(b, -1, n_h, dh)
 
     def out_proj(o):

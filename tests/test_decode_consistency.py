@@ -150,3 +150,84 @@ def test_decode_cache_matches_prefill_cache(case):
         got, ref = np.asarray(got, np.float64), np.asarray(ref, np.float64)
         gap = np.linalg.norm(got - ref) / max(np.linalg.norm(ref), 1e-30)
         assert gap < 0.1, (case, jax.tree_util.keystr(path), gap)
+
+
+# decode's q/k/v projections end in an optimization barrier, which keeps the
+# TPU compiler from re-laying their weights out; these are the attention forms
+# that pass through it: dense GQA, int8 K/V, q/k/v biases, and whisper's
+# cross-attention query beside its cached encoder K/V
+BARRIER_CASES = {
+    "dense_gqa": ("llama3.2-1b", "bfloat16"),
+    "int8_kv": ("llama3.2-1b", "int8"),
+    "qkv_bias": ("qwen2-72b", "bfloat16"),
+    "cross_attention": ("whisper-tiny", "bfloat16"),
+}
+
+
+def _bundle(make, name, kind, kv_dtype="bfloat16"):
+    from repro.configs.base import ShapeConfig
+    from repro.launch.mesh import make_host_mesh
+
+    mesh = make_host_mesh(1, devices=jax.devices()[:1])
+    with jax.set_mesh(mesh):
+        return make(get_arch(name, smoke=True), RunConfig(kv_cache_dtype=kv_dtype),
+                    ShapeConfig("s", 32, 2, kind), mesh), mesh
+
+
+@pytest.mark.parametrize("case", sorted(BARRIER_CASES))
+def test_decode_barrier_changes_no_number(case, monkeypatch):
+    """``make_decode_step``'s logits and caches are the same, bit for bit in
+    bfloat16 compute, with the projections' barrier and without it."""
+    from repro.distributed.steps import make_decode_step
+
+    name, kv_dtype = BARRIER_CASES[case]
+    step, mesh = _bundle(make_decode_step, name, "decode", kv_dtype)
+    keys = iter(jax.random.split(jax.random.PRNGKey(1), 64))
+
+    def draw(a):
+        if jnp.issubdtype(a.dtype, jnp.integer):
+            return jax.random.randint(next(keys), a.shape, -127, 128, a.dtype)
+        return jax.random.normal(next(keys), a.shape, a.dtype)
+
+    params = step.model.init_params(jax.random.PRNGKey(0))
+    caches = jax.tree.map(draw, step.abstract_inputs[1])
+    batch = {"tokens": jnp.asarray([[5], [7]], jnp.int32),
+             "cache_len": jnp.asarray(20, jnp.int32)}
+
+    def run():
+        fn = jax.jit(lambda *a: step.fn(*a))  # a fresh trace on each call
+        with jax.set_mesh(mesh):
+            text = fn.lower(params, caches, batch).as_text()
+            return text, fn(params, caches, batch)
+
+    text, (logits, out_caches) = run()
+    with monkeypatch.context() as m:
+        m.setattr(jax.lax, "optimization_barrier", lambda x: x)
+        plain_text, (plain_logits, plain_caches) = run()
+    assert "optimization_barrier" in text and "optimization_barrier" not in plain_text
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(plain_logits))
+    assert jax.tree.structure(out_caches) == jax.tree.structure(plain_caches)
+    for a, b in zip(jax.tree.leaves(out_caches), jax.tree.leaves(plain_caches)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("kind", ["prefill", "train"])
+@pytest.mark.parametrize("case", sorted(BARRIER_CASES))
+def test_prefill_and_train_lower_without_the_barrier(case, kind, monkeypatch):
+    """Only decode takes the barrier: prefill and train lower to the same
+    program whether ``optimization_barrier`` is real or the identity."""
+    from repro.distributed.steps import make_prefill_step, make_train_step
+
+    name, kv_dtype = BARRIER_CASES[case]
+    make = {"prefill": make_prefill_step, "train": make_train_step}[kind]
+    step, mesh = _bundle(make, name, kind, kv_dtype)
+
+    def lowered():
+        with jax.set_mesh(mesh):
+            return jax.jit(lambda *a: step.fn(*a)).lower(*step.abstract_inputs).as_text()
+
+    text = lowered()
+    with monkeypatch.context() as m:
+        m.setattr(jax.lax, "optimization_barrier", lambda x: x)
+        assert lowered() == text
